@@ -1,7 +1,7 @@
 """Pallas-kernel microbenchmarks.
 
-On this CPU container the kernels dispatch to their jnp reference path (the
-Pallas bodies are validated in interpret mode by tests/test_kernels.py);
+On a CPU the kernels dispatch to their jnp reference path (the Pallas
+bodies are validated in interpret mode by tests/test_kernels.py);
 the numbers here time the REFERENCE path at serving-relevant shapes and
 derive the kernels' arithmetic intensity — the quantity the BlockSpec
 tiling was designed around (see kernels/*/kernel.py docstrings).
@@ -43,6 +43,7 @@ from repro.kernels.fused_head_gate.ref import fused_head_gate_ref
 from repro.kernels.maxconf.ops import maxconf
 from repro.kernels.mdsa.ops import mdsa_distance
 from repro.kernels.rwkv6_scan.ops import rwkv6_time_mix_scan
+from repro.serving.engine import make_gated_local_step
 
 
 def _time(fn, *args, iters=3, **kw):
@@ -70,12 +71,14 @@ def _gate_rows(key) -> list[dict]:
                      "us_per_call": us,
                      "arith_intensity": 6 * b * v / (4 * b * v)})
 
-        # same gate with the early-emit host callback armed: the row
-        # prices the io_callback tax paid per dispatch in continuous
-        # batching (engine hands trusted rows back at gate time)
+        # the served gated step over the same logits with the early-emit
+        # host callback armed: the row prices the io_callback tax paid
+        # per dispatch in continuous batching (engine hands trusted rows
+        # back at gate time)
         fired = []
-        us = _time(confidence_gate, lg, 0.5, supervisor="max_softmax",
-                   k=b, emit=lambda *a: fired.append(a)) * 1e6
+        step = jax.jit(make_gated_local_step(
+            lambda x: x, emit=lambda *a: fired.append(a)))
+        us = _time(step, lg, 0.5, b, 0) * 1e6
         rows.append({"kernel": "confidence_gate_emit",
                      "shape": f"[{b},{v}]", "us_per_call": us,
                      "arith_intensity": 6 * b * v / (4 * b * v)})
@@ -122,11 +125,9 @@ def _gate_checks(key) -> dict:
                               rtol=2e-4, atol=1e-5)))
 
     fired = []
-    out = jax.jit(lambda x: confidence_gate(
-        x, 0.5, supervisor="max_softmax", k=b,
-        emit=lambda tag, conf, pred, idx: fired.append(
-            (int(tag), np.asarray(pred))),
-        emit_tag=7))(logits)
+    out = jax.jit(make_gated_local_step(
+        lambda x: x, emit=lambda tag, conf, pred, idx: fired.append(
+            (int(tag), np.asarray(pred)))))(logits, 0.5, b, 7)
     jax.block_until_ready(out["pred"])
     early_emit_fired = (
         len(fired) == 1 and fired[0][0] == 7

@@ -1,7 +1,7 @@
 """Roofline terms from the compiled dry-run artifact (assignment §Roofline).
 
-This container is CPU-only (TPU v5e is the TARGET, not the runtime), so the
-three terms are *derived* from the compiled module rather than measured:
+The three terms are *derived* from the compiled module (which a CPU host
+can produce for a described TPU v5e), not measured on a chip:
 
     compute term    = HLO_FLOPs / (chips * peak FLOP/s)
     memory term     = HLO_bytes / (chips * HBM bandwidth)

@@ -2,7 +2,10 @@
 
 Each kernel package ships kernel.py (pl.pallas_call + BlockSpec),
 ops.py (jit'd wrapper with CPU fallback) and ref.py (pure-jnp oracle).
-Validated in interpret mode on CPU; TPU v5e is the compile target.
+The CPU tests run every kernel body in Pallas interpret mode;
+tests/test_tpu_compile.py compiles the serve path's gate kernels for a
+described TPU v5e, and ``chip_smoke.py`` checks them against their
+oracles on the chip.
 """
 
 from repro.kernels.confidence_gate.ops import confidence_gate

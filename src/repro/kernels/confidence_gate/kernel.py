@@ -31,6 +31,16 @@ Two kernels compose:
 Grid: scoring is (batch blocks, class blocks) with the class dimension
 innermost ("arbitrary") so per-row scratch carries across class steps;
 selection is a single program over the padded row vector.
+
+Layout: every per-row quantity (scratch, ``conf``/``pred`` outputs) is a
+``[BB, 1]`` column, never a 1-D ``(BB,)`` block. Mosaic accepts a 2-D
+block whose last dim equals the array's (1) and whose row dim is a
+multiple of 8, so any ``bb % 8 == 0`` compiles; a 1-D block would have
+to be the whole array or a multiple of 128 rows.
+
+The op (``ops.py``) composes the two kernels, ``gate_scores_pallas``
+and ``select_pallas``, so a data-parallel caller can score its row shard
+per device and select over the gathered confidences (DESIGN.md §12).
 """
 
 from __future__ import annotations
@@ -41,8 +51,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 NEG = -1e30
 
@@ -67,14 +75,15 @@ def _fold_stats(x, col0, m1, m2, s, t, s2, a1) -> None:
     projection inside the same VMEM tile."""
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
 
-    bm1 = jnp.max(x, axis=1)                               # block max
-    ba1 = jnp.argmax(x, axis=1).astype(jnp.int32) + col0
-    xm = jnp.where(col == ba1[:, None], NEG, x)
-    bm2 = jnp.max(xm, axis=1)                              # block 2nd max
-    e = jnp.exp(x - bm1[:, None])
-    bs = jnp.sum(e, axis=1)
-    bt = jnp.sum(e * x, axis=1)
-    bs2 = jnp.sum(e * e, axis=1)
+    # every per-row statistic stays a [BB, 1] column (module docstring)
+    bm1 = jnp.max(x, axis=1, keepdims=True)                # block max
+    ba1 = jnp.argmax(x, axis=1, keepdims=True).astype(jnp.int32) + col0
+    xm = jnp.where(col == ba1, NEG, x)
+    bm2 = jnp.max(xm, axis=1, keepdims=True)               # block 2nd max
+    e = jnp.exp(x - bm1)
+    bs = jnp.sum(e, axis=1, keepdims=True)
+    bt = jnp.sum(e * x, axis=1, keepdims=True)
+    bs2 = jnp.sum(e * e, axis=1, keepdims=True)
 
     om1, om2, os, ot, os2, oa1 = (m1[...], m2[...], s[...], t[...],
                                   s2[...], a1[...])
@@ -143,37 +152,59 @@ def _select_kernel(t_ref, n_ref, conf_ref, idx_ref, *, k: int, bp: int):
     jax.lax.fori_loop(0, k, body, conf)
 
 
-@functools.partial(jax.jit, static_argnames=("supervisor", "k", "bb", "vb",
+def row_spec(bb: int) -> pl.BlockSpec:
+    """Block of a per-row ``[B, 1]`` output on the (batch, vocab) grid."""
+    return pl.BlockSpec((bb, 1), lambda i, j: (i, 0))
+
+
+def row_outputs(b: int) -> tuple[jax.ShapeDtypeStruct, jax.ShapeDtypeStruct]:
+    """``(conf [B, 1] f32, pred [B, 1] i32)`` output shapes."""
+    return (jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32))
+
+
+def stats_scratch(bb: int) -> list:
+    """VMEM for the running statistics (m1, m2, s, t, s2; a1)."""
+    return ([pltpu.VMEM((bb, 1), jnp.float32)] * 5
+            + [pltpu.VMEM((bb, 1), jnp.int32)])
+
+
+@functools.partial(jax.jit, static_argnames=("supervisor", "bb", "vb",
                                              "interpret"))
-def confidence_gate_pallas(logits: jnp.ndarray, t_local: jnp.ndarray,
-                           n_valid: jnp.ndarray, *, supervisor: str,
-                           k: int, bb: int = 8, vb: int = 128,
-                           interpret: bool = False) -> dict[str, jnp.ndarray]:
-    """logits [B, C] (B % bb == 0, C % vb == 0), t_local f32 scalar
-    (+inf = no threshold), n_valid i32 scalar -> {conf, pred, idx}."""
+def gate_scores_pallas(logits: jnp.ndarray, *, supervisor: str,
+                       bb: int = 8, vb: int = 128,
+                       interpret: bool = False
+                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """logits [B, C] (B % bb == 0, C % vb == 0) -> (conf [B], pred [B])."""
     b, v = logits.shape
     assert b % bb == 0 and v % vb == 0, (b, v, bb, vb)
     assert supervisor in SUPERVISORS, supervisor
     nb, nv = b // bb, v // vb
-
-    row_spec = pl.BlockSpec((bb,), lambda i, j: (i,))
     conf, pred = pl.pallas_call(
         functools.partial(_score_kernel, nv=nv, vb=vb, supervisor=supervisor),
         grid=(nb, nv),
         in_specs=[pl.BlockSpec((bb, vb), lambda i, j: (i, j))],
-        out_specs=(row_spec, row_spec),
-        out_shape=(jax.ShapeDtypeStruct((b,), jnp.float32),
-                   jax.ShapeDtypeStruct((b,), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((bb,), jnp.float32)] * 5
-                       + [pltpu.VMEM((bb,), jnp.int32)],
+        out_specs=(row_spec(bb), row_spec(bb)),
+        out_shape=row_outputs(b),
+        scratch_shapes=stats_scratch(bb),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(logits)
+    return conf[:, 0], pred[:, 0]
 
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def select_pallas(conf: jnp.ndarray, t_local: jnp.ndarray,
+                  n_valid: jnp.ndarray, *, k: int,
+                  interpret: bool = False) -> jnp.ndarray:
+    """conf [B], t_local f32 scalar (+inf = no threshold), n_valid i32
+    scalar -> idx [k]: ascending-confidence rows below ``t_local`` among
+    rows ``< n_valid``, -1-padded."""
+    b = conf.shape[0]
     bp = b + (-b) % 128                                    # lane-align rows
     conf_row = jnp.full((1, bp), jnp.inf, jnp.float32).at[0, :b].set(conf)
-    idx = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_select_kernel, k=k, bp=bp),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -183,4 +214,3 @@ def confidence_gate_pallas(logits: jnp.ndarray, t_local: jnp.ndarray,
         interpret=interpret,
     )(jnp.asarray(t_local, jnp.float32).reshape(1),
       jnp.asarray(n_valid, jnp.int32).reshape(1), conf_row)
-    return {"conf": conf, "pred": pred, "idx": idx}

@@ -21,17 +21,21 @@ import jax.numpy as jnp
 from repro.core.supervisors import SOFTMAX_SUPERVISORS
 
 
-def confidence_gate_ref(logits: jnp.ndarray, t_local=None, n_valid=None, *,
-                        supervisor="max_softmax",
-                        k: int | None = None) -> dict[str, jnp.ndarray]:
-    """logits [B, C] -> {conf [B] f32, pred [B] i32, idx [k] i32}."""
-    b = logits.shape[0]
-    k = b if k is None else min(int(k), b)
+def gate_scores_ref(logits: jnp.ndarray, *, supervisor="max_softmax"
+                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """logits [B, C] -> (conf [B] f32, pred [B] i32)."""
     sup = (supervisor if callable(supervisor)
            else SOFTMAX_SUPERVISORS[supervisor])
-    conf = sup(logits).astype(jnp.float32)
-    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (sup(logits).astype(jnp.float32),
+            jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
+
+def select_ref(conf: jnp.ndarray, t_local=None, n_valid=None, *,
+               k: int | None = None) -> jnp.ndarray:
+    """conf [B] -> idx [k] i32 (ascending-confidence escalation
+    candidates, -1-padded)."""
+    b = conf.shape[0]
+    k = b if k is None else min(int(k), b)
     t = jnp.float32(jnp.inf) if t_local is None else \
         jnp.asarray(t_local, jnp.float32)
     n = jnp.int32(b) if n_valid is None else jnp.asarray(n_valid, jnp.int32)
@@ -41,5 +45,13 @@ def confidence_gate_ref(logits: jnp.ndarray, t_local=None, n_valid=None, *,
     order = jnp.argsort(masked).astype(jnp.int32)        # stable ascending
     # eligible rows form a prefix of the ascending order
     count = jnp.sum((masked[order[:k]] < t).astype(jnp.int32))
-    idx = jnp.where(jnp.arange(k, dtype=jnp.int32) < count, order[:k], -1)
-    return {"conf": conf, "pred": pred, "idx": idx}
+    return jnp.where(jnp.arange(k, dtype=jnp.int32) < count, order[:k], -1)
+
+
+def confidence_gate_ref(logits: jnp.ndarray, t_local=None, n_valid=None, *,
+                        supervisor="max_softmax",
+                        k: int | None = None) -> dict[str, jnp.ndarray]:
+    """logits [B, C] -> {conf [B] f32, pred [B] i32, idx [k] i32}."""
+    conf, pred = gate_scores_ref(logits, supervisor=supervisor)
+    return {"conf": conf, "pred": pred,
+            "idx": select_ref(conf, t_local, n_valid, k=k)}

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG = -1e30
 
@@ -96,7 +95,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                         pltpu.VMEM((g,), jnp.float32),
                         pltpu.VMEM((g,), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(lens, qr, kr, vr)
     return out.reshape(b, kh, g, hd).reshape(b, h, hd)

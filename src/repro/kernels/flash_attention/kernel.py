@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG = -1e30
 
@@ -126,7 +125,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         pltpu.VMEM((g * qb,), jnp.float32),
                         pltpu.VMEM((g * qb,), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr)
     return (out.reshape(b, kh, g, t, hd).transpose(0, 3, 1, 2, 4)

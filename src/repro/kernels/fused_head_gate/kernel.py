@@ -17,10 +17,12 @@ Grid: (batch blocks, vocab blocks) with the vocab dimension innermost
 ("arbitrary") so the per-row scratch carries across vocab steps —
 identical to the score kernel's schedule, plus one ``[BB, D] x [D, VB]``
 dot per step (``preferred_element_type=f32`` keeps the MXU accumulator
-in full precision). Selection reuses the gate's ``_select_kernel``
-unchanged: thresholded ascending bottom-k over the [B] confidences with
-SMEM-scalar ``t_local``/``n_valid``, so runtime retuning (paper §4.5)
-never recompiles.
+in full precision). Selection is the gate's ``select_pallas``, applied
+by the op (``fused_head_gate/ops.py``): thresholded ascending bottom-k
+over the [B] confidences with SMEM-scalar ``t_local``/``n_valid``, so
+runtime retuning (paper §4.5) never recompiles. Per-row scratch and
+outputs are ``[BB, 1]`` columns and the bias is a ``[1, C]`` row (the
+gate's layout rules, see ``confidence_gate/kernel.py``).
 """
 
 from __future__ import annotations
@@ -32,10 +34,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.confidence_gate.kernel import (_fold_stats, _init_stats,
-                                                  _select_kernel,
-                                                  _stats_epilogue)
+                                                  _stats_epilogue, row_outputs,
+                                                  row_spec, stats_scratch)
 
 
 def _head_gate_kernel(h_ref, w_ref, b_ref, conf_ref, pred_ref,
@@ -50,7 +51,7 @@ def _head_gate_kernel(h_ref, w_ref, b_ref, conf_ref, pred_ref,
     h = h_ref[...].astype(jnp.float32)                     # [BB, D]
     w = w_ref[...].astype(jnp.float32)                     # [D, VB]
     x = jnp.dot(h, w, preferred_element_type=jnp.float32)  # logits tile
-    x = x + b_ref[...][None, :]
+    x = x + b_ref[...]                                     # [1, VB]
     _fold_stats(x, j * vb, m1, m2, s, t, s2, a1)
 
     @pl.when(j == nv - 1)
@@ -59,52 +60,33 @@ def _head_gate_kernel(h_ref, w_ref, b_ref, conf_ref, pred_ref,
                         supervisor=supervisor)
 
 
-@functools.partial(jax.jit, static_argnames=("supervisor", "k", "bb", "vb",
+@functools.partial(jax.jit, static_argnames=("supervisor", "bb", "vb",
                                              "interpret"))
-def fused_head_gate_pallas(hidden: jnp.ndarray, w: jnp.ndarray,
-                           bias: jnp.ndarray, t_local: jnp.ndarray,
-                           n_valid: jnp.ndarray, *, supervisor: str,
-                           k: int, bb: int = 8, vb: int = 128,
-                           interpret: bool = False
-                           ) -> dict[str, jnp.ndarray]:
-    """hidden [B, D] (B % bb == 0), w [D, C] (C % vb == 0), bias [C],
-    t_local f32 scalar (+inf = no threshold), n_valid i32 scalar ->
-    {conf, pred, idx}."""
+def head_gate_scores_pallas(hidden: jnp.ndarray, w: jnp.ndarray,
+                            bias: jnp.ndarray, *, supervisor: str,
+                            bb: int = 8, vb: int = 128,
+                            interpret: bool = False
+                            ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """hidden [B, D] (B % bb == 0), w [D, C] (C % vb == 0), bias [C] ->
+    (conf [B], pred [B])."""
     b, d = hidden.shape
     dw, v = w.shape
     assert d == dw and bias.shape == (v,), (hidden.shape, w.shape,
                                             bias.shape)
     assert b % bb == 0 and v % vb == 0, (b, v, bb, vb)
     nb, nv = b // bb, v // vb
-
-    row_spec = pl.BlockSpec((bb,), lambda i, j: (i,))
     conf, pred = pl.pallas_call(
         functools.partial(_head_gate_kernel, nv=nv, vb=vb,
                           supervisor=supervisor),
         grid=(nb, nv),
         in_specs=[pl.BlockSpec((bb, d), lambda i, j: (i, 0)),
                   pl.BlockSpec((d, vb), lambda i, j: (0, j)),
-                  pl.BlockSpec((vb,), lambda i, j: (j,))],
-        out_specs=(row_spec, row_spec),
-        out_shape=(jax.ShapeDtypeStruct((b,), jnp.float32),
-                   jax.ShapeDtypeStruct((b,), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((bb,), jnp.float32)] * 5
-                       + [pltpu.VMEM((bb,), jnp.int32)],
+                  pl.BlockSpec((1, vb), lambda i, j: (0, j))],
+        out_specs=(row_spec(bb), row_spec(bb)),
+        out_shape=row_outputs(b),
+        scratch_shapes=stats_scratch(bb),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(hidden, w, bias)
-
-    bp = b + (-b) % 128                                    # lane-align rows
-    conf_row = jnp.full((1, bp), jnp.inf, jnp.float32).at[0, :b].set(conf)
-    idx = pl.pallas_call(
-        functools.partial(_select_kernel, k=k, bp=bp),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((k,), jnp.int32),
-        interpret=interpret,
-    )(jnp.asarray(t_local, jnp.float32).reshape(1),
-      jnp.asarray(n_valid, jnp.int32).reshape(1), conf_row)
-    return {"conf": conf, "pred": pred, "idx": idx}
+    )(hidden, w, bias.reshape(1, v))
+    return conf[:, 0], pred[:, 0]

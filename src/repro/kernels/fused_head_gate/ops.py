@@ -1,21 +1,21 @@
 """Jit-friendly public wrapper for the fused local-head -> gate kernel.
 
 On TPU dispatches to the fused Pallas kernel (logits tiles live only in
-VMEM; just ``(conf, pred, idx)`` leaves the device); elsewhere (this CPU
-container) falls back to the jnp oracle so the serving engine uses one
-API everywhere. Padding mirrors ``confidence_gate``: vocab padding adds
-zero weight columns with ``-1e30`` bias (so padded logits carry no
-softmax mass and never win the argmax); batch padding adds zero rows
-excluded from selection via ``n_valid``.
+VMEM; just ``(conf, pred, idx)`` leaves the device); on any other backend
+it takes the jnp oracle (or, with ``force_pallas``, the kernel body in
+Pallas interpret mode) so the serving engine uses one API everywhere.
+Padding mirrors ``confidence_gate``: vocab padding adds zero weight
+columns with ``-1e30`` bias (so padded logits carry no softmax mass and
+never win the argmax); batch padding adds zero rows, cut before
+selection.
 
 ``FusedLocalHead`` is the engine-facing carrier: a local model split as
 ``trunk`` (inputs -> hidden [B, D]) plus the final projection ``(w
 [D, C], bias [C])``. ``CascadeEngine`` accepts it anywhere a plain
 ``local_apply`` is accepted and routes the gate through this fused op.
 
-Early emit composes the same way as the standalone gate: pass ``emit``/
-``emit_tag`` and the triple is surfaced through ``io_callback`` the
-moment it lands (see confidence_gate.ops).
+Selection and the ``gather`` hook are the standalone gate's (see
+confidence_gate.ops); one ``use_pallas`` decision steers both passes.
 """
 
 from __future__ import annotations
@@ -23,13 +23,13 @@ from __future__ import annotations
 from collections.abc import Callable
 from dataclasses import dataclass
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.confidence_gate.kernel import SUPERVISORS
-from repro.kernels.confidence_gate.ops import _emit_gate, _on_tpu
-from repro.kernels.fused_head_gate.kernel import fused_head_gate_pallas
-from repro.kernels.fused_head_gate.ref import fused_head_gate_ref
+from repro.kernels.confidence_gate.ops import (_on_tpu, select_candidates,
+                                               use_pallas)
+from repro.kernels.confidence_gate.ref import gate_scores_ref
+from repro.kernels.fused_head_gate.kernel import head_gate_scores_pallas
+from repro.kernels.fused_head_gate.ref import head_logits_ref
 
 NEG = -1e30
 
@@ -49,44 +49,24 @@ class FusedLocalHead:
     bias: jnp.ndarray | None = None                        # [C]
 
     def __call__(self, local_batch) -> jnp.ndarray:
-        h = self.trunk(local_batch)
-        logits = jnp.dot(h.astype(jnp.float32), self.w.astype(jnp.float32))
-        if self.bias is not None:
-            logits = logits + self.bias.astype(jnp.float32)[None, :]
-        return logits
+        return head_logits_ref(self.trunk(local_batch), self.w, self.bias)
 
 
-def fused_head_gate(hidden: jnp.ndarray, w: jnp.ndarray,
-                    bias: jnp.ndarray | None = None, t_local=None,
-                    n_valid=None, *, supervisor="max_softmax",
-                    k: int | None = None, bb: int = 8, vb: int = 128,
-                    force_pallas: bool = False, interpret: bool = False,
-                    emit=None, emit_tag=None) -> dict[str, jnp.ndarray]:
-    """hidden [B, D], w [D, C], bias [C]|None -> {conf [B], pred [B],
-    idx [k]} without materialising the [B, C] logits in HBM.
-
-    Same contract as ``confidence_gate`` (idx: ascending-confidence
-    escalation candidates below ``t_local`` among rows ``< n_valid``,
-    -1-padded); ``emit``/``emit_tag`` opt into the early-emit host
-    callback.
-    """
+def head_gate_scores(hidden: jnp.ndarray, w: jnp.ndarray,
+                     bias: jnp.ndarray | None = None, *,
+                     supervisor="max_softmax", bb: int = 8, vb: int = 128,
+                     pallas: bool = False, interpret: bool = False
+                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """hidden [B, D], w [D, C], bias [C]|None -> (conf [B], pred [B]),
+    the fused projection + scoring pass; ``pallas`` picks the kernel over
+    the jnp oracle."""
     b, d = hidden.shape
     dw, v = w.shape
     if d != dw:
         raise ValueError(f"hidden dim {d} != head dim {dw}")
-    k = b if k is None else min(int(k), b)
-    if callable(supervisor) or not (force_pallas or _on_tpu()):
-        out = fused_head_gate_ref(hidden, w, bias, t_local, n_valid,
-                                  supervisor=supervisor, k=k)
-        if emit is not None:
-            _emit_gate(emit, emit_tag, out)
-        return out
-    if supervisor not in SUPERVISORS:
-        raise ValueError(f"unknown supervisor {supervisor!r}; "
-                         f"expected one of {SUPERVISORS}")
-    t = jnp.float32(jnp.inf) if t_local is None else \
-        jnp.asarray(t_local, jnp.float32)
-    n = jnp.int32(b) if n_valid is None else jnp.asarray(n_valid, jnp.int32)
+    if not pallas:
+        return gate_scores_ref(head_logits_ref(hidden, w, bias),
+                               supervisor=supervisor)
     bias = jnp.zeros((v,), jnp.float32) if bias is None else \
         jnp.asarray(bias, jnp.float32)
     pad_b = (-b) % bb
@@ -96,13 +76,30 @@ def fused_head_gate(hidden: jnp.ndarray, w: jnp.ndarray,
         bias = jnp.pad(bias, (0, pad_v), constant_values=NEG)
     if pad_b:
         hidden = jnp.pad(hidden, ((0, pad_b), (0, 0)))
-        n = jnp.minimum(n, b)                  # padded rows never escalate
-    out = fused_head_gate_pallas(hidden, w, bias, t, n,
-                                 supervisor=supervisor, k=k, bb=bb, vb=vb,
-                                 interpret=interpret or not _on_tpu())
-    if pad_b:
-        out = {"conf": out["conf"][:b], "pred": out["pred"][:b],
-               "idx": out["idx"]}
-    if emit is not None:
-        _emit_gate(emit, emit_tag, out)
-    return out
+    conf, pred = head_gate_scores_pallas(
+        hidden, w, bias, supervisor=supervisor, bb=bb, vb=vb,
+        interpret=interpret or not _on_tpu())
+    return conf[:b], pred[:b]
+
+
+def fused_head_gate(hidden: jnp.ndarray, w: jnp.ndarray,
+                    bias: jnp.ndarray | None = None, t_local=None,
+                    n_valid=None, *, supervisor="max_softmax",
+                    k: int | None = None, bb: int = 8, vb: int = 128,
+                    force_pallas: bool = False, interpret: bool = False,
+                    gather=None) -> dict[str, jnp.ndarray]:
+    """hidden [B, D], w [D, C], bias [C]|None -> {conf [B], pred [B],
+    idx [k]} without materialising the [B, C] logits in HBM.
+
+    Same contract as ``confidence_gate`` (idx: ascending-confidence
+    escalation candidates below ``t_local`` among rows ``< n_valid``,
+    -1-padded; ``gather`` widens the confidences selection runs over).
+    """
+    pallas = use_pallas(supervisor, force_pallas)
+    conf, pred = head_gate_scores(hidden, w, bias, supervisor=supervisor,
+                                  bb=bb, vb=vb, pallas=pallas,
+                                  interpret=interpret)
+    conf_all = conf if gather is None else gather(conf)
+    return {"conf": conf, "pred": pred,
+            "idx": select_candidates(conf_all, t_local, n_valid, k=k,
+                                     pallas=pallas, interpret=interpret)}

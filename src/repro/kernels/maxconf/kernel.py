@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG = -1e30
 
@@ -99,7 +98,7 @@ def maxconf_pallas(logits: jnp.ndarray, *, bb: int = 8, vb: int = 2048,
         scratch_shapes=[pltpu.VMEM((bb,), jnp.float32)] * 4
                        + [pltpu.VMEM((bb,), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(logits)
     return {"prediction": pred, "max_softmax": ms, "pcs": pcs,

@@ -1,6 +1,6 @@
 """Jit'd public wrapper for the fused supervisor-confidence kernel.
 
-On TPU dispatches to the Pallas kernel; elsewhere (this CPU container)
+On TPU dispatches to the Pallas kernel; elsewhere (e.g. the CPU tests)
 falls back to the jnp oracle, so callers use one API everywhere. Pads the
 batch to the block multiple when needed.
 """

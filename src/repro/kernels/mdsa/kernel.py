@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _kernel(y_i_ref, p_ref, y_j_ref, out_ref, z, d2, *, nd: int):
@@ -71,6 +70,6 @@ def mdsa_pallas(x: jnp.ndarray, mean: jnp.ndarray, prec: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((bb, db), jnp.float32),
                         pltpu.VMEM((bb,), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(y, prec, y)

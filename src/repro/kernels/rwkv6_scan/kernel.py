@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
@@ -90,7 +89,7 @@ def rwkv6_scan(r, k, v, w, u, s0, *, tb: int = 128,
         ),
         scratch_shapes=[pltpu.VMEM((m, m), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(rr, kk, vv, ww, uu, ss)
     y = y.reshape(b, h, t, m).transpose(0, 2, 1, 3)

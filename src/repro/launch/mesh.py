@@ -9,29 +9,17 @@ from __future__ import annotations
 import jax
 
 
-def axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for jax.make_mesh, empty on older jax
-    releases that predate ``jax.sharding.AxisType`` (e.g. 0.4.x)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def use_abstract_mesh(abstract_mesh):
-    """`jax.sharding.use_abstract_mesh`, falling back to the internal
-    context manager on older releases where it is not yet public."""
-    fn = getattr(jax.sharding, "use_abstract_mesh", None)
-    if fn is None:
-        from jax._src.mesh import set_abstract_mesh as fn
-    return fn(abstract_mesh)
+def _auto(n_axes: int) -> tuple:
+    """Every mesh axis in Auto mode: the SPMD partitioner places
+    activations, steered by the sharding hints (models/shard_hints.py)."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, _auto(len(axes)))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:
@@ -41,7 +29,7 @@ def batch_axes(mesh) -> tuple[str, ...]:
 
 def make_host_mesh():
     """Single-device mesh with the same axis names (CPU tests/examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **axis_type_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"), _auto(2))
 
 
 def make_serving_mesh():
@@ -55,4 +43,4 @@ def make_serving_mesh():
     is numerically identical to the unsharded one.
     """
     n = jax.local_device_count()
-    return jax.make_mesh((n, 1), ("data", "model"), **axis_type_kwargs(2))
+    return jax.make_mesh((n, 1), ("data", "model"), _auto(2))

@@ -46,7 +46,13 @@ runs (``GET /metrics`` Prometheus text, ``/metrics.json`` snapshot,
 and ``--trace-chrome`` exports Chrome ``trace_event`` JSON for perfetto.
 Any of these implies ``observability=True`` on the ``ServeConfig``.
 
-On this CPU container use ``--smoke`` (reduced remote config).
+Without ``--smoke`` the remote tier runs at the architecture's published
+widths (Yi-6B: 12.1 GB of bf16 parameters, one TPU v5e). On a CPU use
+``--smoke`` (reduced remote config); ``chip_smoke.py`` at the repo root
+drives this module on the chip. Every shape the serve loop dispatches is
+compiled before the timed loop: the remote forward runs in fixed windows
+of ``transport.max_in_flight`` rows, and the engine's gated local step is
+warmed on a full batch.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --remote-arch yi-6b \
@@ -62,6 +68,7 @@ import json
 import threading
 import time
 from collections import Counter
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +77,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.thresholds import nominal_quantile_threshold
 from repro.data.synthetic import make_classification_task
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import surrogate as S
 from repro.models import transformer as T
 from repro.runtime import calibrate, content_key, content_keys
@@ -96,6 +104,34 @@ def train_surrogate(cfg, toks, labels, steps=60, lr=3e-3, seed=0):
     return params, float(loss)
 
 
+@dataclass
+class ServeResult:
+    """What one ``run`` served. Holds host values only, no device arrays,
+    so the model it served is freed once ``run`` returns."""
+    responses: list
+    wall_s: float               # timed serve loop
+    compile_s: float            # warm-up: every shape the loop dispatches
+    init_s: float               # remote parameter init, its compile included
+    pallas_gate: bool           # compiled local step holds a Pallas kernel
+    gate_emits: int = 0         # early-emit callbacks that landed
+    # transport faults summed over backends (+ unrouted windows): a
+    # healthy run has all zero; fallbacks from the 2nd supervisor are not
+    # faults and are not counted here
+    faults: dict = field(default_factory=dict)
+
+
+def _fault_counts(router) -> dict:
+    out = dict.fromkeys(("errors", "timeouts", "short_circuited",
+                         "breaker_opens", "failed_requests"), 0)
+    if router is None:
+        return out
+    for b in router:
+        for k in out:
+            out[k] += getattr(b.stats, k)
+    out["unrouted"] = router.stats.unrouted
+    return out
+
+
 def build_serve_config(args) -> ServeConfig:
     """One ``ServeConfig`` from the CLI: first-class workload flags, then
     the repeatable ``--set key=value`` field overrides (DESIGN.md §8)."""
@@ -110,11 +146,14 @@ def build_serve_config(args) -> ServeConfig:
 
 
 def _serve_cluster(args, cfg, router, local_apply, toks, local_toks,
-                   labels, rcfg) -> int:
+                   labels, rcfg, ncls,
+                   compile_s: float) -> tuple[list, float, float, str]:
     """Replicated serving (DESIGN.md §12): ``cfg.replicas`` engines
     behind one logical cascade — one shared router, a single-fill
     shared response cache and a cluster budget reconciler re-weighting
-    per-replica targets. Requests round-robin across replicas."""
+    per-replica targets. Requests round-robin across replicas. Returns
+    the responses, the loop's wall seconds, ``compile_s`` plus the
+    replicas' warm-up, and a replica's compiled local step (HLO text)."""
     from repro.runtime.cluster import ClusterHarness
 
     harness = ClusterHarness(
@@ -123,6 +162,14 @@ def _serve_cluster(args, cfg, router, local_apply, toks, local_toks,
         cache_key_fn=lambda row: content_key(row["tokens"]),
         cache_key_batch_fn=lambda b, n: content_keys(b["tokens"], n))
     names = harness.names
+    hlo = ""
+    t_compile = time.perf_counter()
+    for name in names:
+        hlo = harness.replica(name).engine.warmup(
+            local_toks[:cfg.batch_size], ncls)
+    compile_s += time.perf_counter() - t_compile
+    print(f"[serve] compile: {compile_s:.3f}s (every program the serve "
+          f"loop dispatches, first call each, {cfg.replicas} replicas)")
     print(f"[serve] cluster: {cfg.replicas} replicas {names}, shared "
           f"cache {'on' if harness.shared_cache is not None else 'off'}, "
           f"reconcile every {harness.reconcile_interval_s:.1f}s")
@@ -232,10 +279,12 @@ def _serve_cluster(args, cfg, router, local_apply, toks, local_toks,
         with open(args.metrics_dump, "w") as f:
             f.write(text)
         print(f"[serve] wrote metrics snapshot -> {args.metrics_dump}")
-    return 0
+    return responses, wall, compile_s, hlo
 
 
-def main(argv=None) -> int:
+def run(argv=None) -> ServeResult:
+    """Parse the CLI, build the cascade, serve ``--requests`` requests and
+    print the accounting; ``main`` is this plus an exit code."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--remote-arch", default="yi-6b")
     ap.add_argument("--smoke", action="store_true")
@@ -298,42 +347,73 @@ def main(argv=None) -> int:
                  "(--metrics-dump / --metrics-interval / --metrics-port "
                  "serve the merged fleet registry, replica-labelled); "
                  "per-replica tracing is a follow-on (DESIGN.md §12)")
+    enable_compile_cache()
+    devs = jax.devices()
+    print(f"[serve] devices: {len(devs)} x {devs[0].platform} "
+          f"({devs[0].device_kind})")
 
     # ---- task + local surrogate (paper §4.1: input-domain-reduced) ----
+    # rows [0, requests) are served; the surrogate trains on the next 512
+    # and both tiers are calibrated on the 128 after those, so neither the
+    # training nor the calibration slice overlaps what is served
     vocab, seq, ncls = 512, 48, 8
-    n = max(args.requests, 512)
+    train = slice(args.requests, args.requests + 512)
+    val = slice(train.stop, train.stop + 128)
     toks, labels, _ = make_classification_task(
-        1, n=n, vocab=vocab, seq_len=seq, num_classes=ncls)
+        1, n=val.stop, vocab=vocab, seq_len=seq, num_classes=ncls)
     scfg = S.SurrogateConfig("local", vocab_size=vocab // 4, max_len=seq // 2,
                              d_model=32, num_heads=2, d_ff=32,
                              num_classes=ncls, dropout=0.0)
     # input-domain reduction: clipped seq, folded vocab
     local_toks = (toks[:, : seq // 2] % (vocab // 4)).astype(np.int32)
-    sparams, sloss = train_surrogate(scfg, jnp.asarray(local_toks[:512]),
-                                     jnp.asarray(labels[:512]))
+    sparams, sloss = train_surrogate(scfg, jnp.asarray(local_toks[train]),
+                                     jnp.asarray(labels[train]))
     print(f"[serve] local surrogate trained (final loss {sloss:.3f})")
 
     # ---- remote tier: a sharded in-framework model ----
     rcfg = get_config(args.remote_arch)
     if args.smoke:
         rcfg = rcfg.reduced()
-    ndev = len(jax.devices())
-    rparams = T.init_params(rcfg, jax.random.PRNGKey(7))
-    print(f"[serve] remote tier {rcfg.name} on {ndev} device(s)")
+    # jitted: an eager init would draw each stacked weight in f32 before
+    # the cast, several GB per leaf at full width
+    t_init = time.perf_counter()
+    rparams = jax.block_until_ready(jax.jit(
+        lambda k: T.init_params(rcfg, k))(jax.random.PRNGKey(7)))
+    init_s = time.perf_counter() - t_init
+    print(f"[serve] remote tier {rcfg.name} on {len(devs)} device(s); "
+          f"init {init_s:.3f}s (jitted, compile included)")
 
     # the remote model consumes the FULL input (no domain reduction); its
     # last-position hidden is decoded by a task head. For the demo the head
     # is an oracle readout so the remote tier is accurate (stands in for a
     # GPT-3-quality model, as in the paper's case studies).
     oracle = jax.nn.one_hot(jnp.asarray(labels), ncls) * 8.0
+    window = max(1, cfg.transport.max_in_flight)
 
-    def remote_apply(batch):
-        toks_full, idx = batch["tokens"], batch["idx"]
-        logits, _ = T.prefill(rcfg, rparams, {"tokens": toks_full})
+    @jax.jit
+    def remote_forward(params, toks_full, idx):
+        logits, _ = T.prefill(rcfg, params, {"tokens": toks_full})
         # project LM logits to task classes via oracle head (+ tiny noise
         # from the real hidden state so confidences vary per input)
         jitter = 0.01 * logits[:, :ncls].astype(jnp.float32)
         return oracle[idx] + jitter
+
+    def remote_apply(batch):
+        # whole windows of `window` rows (the tail padded with its last
+        # row): one compiled shape serves every call the transport makes
+        toks_full = np.asarray(batch["tokens"])
+        idx = np.asarray(batch["idx"])
+        n = toks_full.shape[0]
+        rows = np.r_[np.arange(n), np.full((-n) % window, n - 1)]
+        outs = [remote_forward(rparams, toks_full[rows[lo:lo + window]],
+                               idx[rows[lo:lo + window]])
+                for lo in range(0, rows.size, window)]
+        return np.concatenate(jax.device_get(outs))[:n]
+
+    t_compile = time.perf_counter()
+    jax.block_until_ready(remote_apply(
+        {"tokens": toks[:1] % rcfg.vocab_size, "idx": np.arange(1)}))
+    compile_s = time.perf_counter() - t_compile
 
     def local_apply(tk):
         return S.apply(scfg, sparams, tk)
@@ -344,8 +424,8 @@ def main(argv=None) -> int:
 
     # ---- 2nd-level threshold: nominal-quantile calibration (§4.5) ----
     cal_logits = np.asarray(remote_apply(
-        {"tokens": jnp.asarray(toks[:128] % rcfg.vocab_size),
-         "idx": jnp.arange(128)}))
+        {"tokens": jnp.asarray(toks[val] % rcfg.vocab_size),
+         "idx": jnp.arange(val.start, val.stop)}))
     cal_conf = np.max(
         np.exp(cal_logits) / np.exp(cal_logits).sum(-1, keepdims=True), -1)
     if "t_remote" not in user_set:
@@ -373,17 +453,16 @@ def main(argv=None) -> int:
         # offline Pareto sweep on a labelled validation slice (DESIGN.md §1)
         # — priced at the policy-preferred backend's per-call cost when a
         # registry is configured, selected by $ when cost_budget is set
-        nval = cal_logits.shape[0]
-        val_logits = np.asarray(local_apply(jnp.asarray(local_toks[:nval])))
+        val_logits = np.asarray(local_apply(jnp.asarray(local_toks[val])))
         val_sm = np.exp(val_logits) / np.exp(val_logits).sum(-1, keepdims=1)
         esc_cost = (cfg.cost or CostModel()).remote_cost_per_request
         if router is not None:
             esc_cost = router.expected_cost_per_escalation(esc_cost)
         point, k, front = calibrate(
             local_conf=val_sm.max(-1),
-            local_correct=val_logits.argmax(-1) == labels[:nval],
+            local_correct=val_logits.argmax(-1) == labels[val],
             remote_conf=cal_conf,
-            remote_correct=cal_logits.argmax(-1) == labels[:nval],
+            remote_correct=cal_logits.argmax(-1) == labels[val],
             budget=(None if cfg.cost_budget is not None
                     else cfg.remote_fraction_budget),
             cost_budget=cfg.cost_budget, batch_size=cfg.batch_size,
@@ -404,16 +483,35 @@ def main(argv=None) -> int:
 
     # ---- replicated serving: N engines, one logical cascade ----
     if cfg.replicas > 1:
-        return _serve_cluster(args, cfg, router, local_apply, toks,
-                              local_toks, labels, rcfg)
+        responses, wall, compile_s, hlo = _serve_cluster(
+            args, cfg, router, local_apply, toks, local_toks, labels, rcfg,
+            ncls, compile_s)
+        return ServeResult(responses, wall, compile_s, init_s,
+                           "tpu_custom_call" in hlo,
+                           faults=_fault_counts(router))
 
     # ---- the whole serving stack from the one ServeConfig ----
+    warm = slice(0, cfg.batch_size)
+    remote_warm = None
     if cfg.fused:
-        eng, sched = cfg.build(local_apply, remote_apply,
-                               fallback=lambda r: -1)
+        # the fused step calls the remote tier inside its own jit
+        eng, sched = cfg.build(
+            local_apply,
+            lambda b: remote_forward(rparams, b["tokens"], b["idx"]),
+            fallback=lambda r: -1)
+        remote_warm = {"tokens": toks[warm] % rcfg.vocab_size,
+                       "idx": np.arange(cfg.batch_size, dtype=np.int32)}
     else:
         eng, sched = cfg.build(local_apply, transport=router, cache=cache,
                                fallback=lambda r: -1)
+    t_compile = time.perf_counter()
+    hlo = eng.warmup(local_toks[warm], ncls, remote_warm)
+    compile_s += time.perf_counter() - t_compile
+    # the fused step scores in jnp: a custom call there is the remote tier's
+    pallas_gate = not cfg.fused and "tpu_custom_call" in hlo
+    print(f"[serve] compile: {compile_s:.3f}s (every program the serve "
+          f"loop dispatches, first call each); local gate "
+          f"{'Pallas' if pallas_gate else 'jnp reference'}")
 
     obs = eng.observability
 
@@ -579,6 +677,13 @@ def main(argv=None) -> int:
         if args.metrics_dump:
             dump_metrics(args.metrics_dump)
             print(f"[serve] wrote metrics snapshot -> {args.metrics_dump}")
+    return ServeResult(responses, wall, compile_s, init_s, pallas_gate,
+                       gate_emits=eng._gate_emits,
+                       faults=_fault_counts(router))
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

@@ -204,29 +204,3 @@ def logits_sharding(cfg: ModelConfig, mesh, batch: int) -> NamedSharding:
 
 def replicated(mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
-
-
-# --------------------------------------------------------------------------
-# serving (DESIGN.md §12): data-parallel local forward
-# --------------------------------------------------------------------------
-
-def shard_batch(batch: Any, mesh) -> Any:
-    """Constrain every leaf of a stacked request pytree to batch-dim
-    data parallelism on ``mesh`` (leading dim over ("pod","data") when
-    divisible, replicated otherwise). Safe inside ``jit`` — leaves are
-    tracers and only their static shapes are inspected."""
-    return jax.tree.map(
-        lambda x: jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, batch_spec(mesh, x.shape))),
-        batch)
-
-
-def shard_local_step(step: Any, mesh) -> Any:
-    """Wrap a gated local step so its input batch is data-parallel on
-    ``mesh``. The wrapper preserves the step signature (positional
-    ``(local_batch, t_local, n_valid, ...)``); thresholds and row counts
-    stay replicated scalars. On a 1-device mesh this is a no-op
-    constraint and the compiled computation is unchanged."""
-    def sharded_step(local_batch, *rest):
-        return step(shard_batch(local_batch, mesh), *rest)
-    return sharded_step

@@ -16,7 +16,6 @@ from jax.sharding import NamedSharding
 
 from repro.configs.base import INPUT_SHAPES, ModelConfig, ShapeConfig
 from repro.launch import sharding as sh
-from repro.launch.mesh import use_abstract_mesh
 from repro.models import transformer as T
 from repro.train.loop import make_train_step
 from repro.train.optimizer import AdamWConfig, init_opt_state
@@ -128,7 +127,7 @@ def lower_step(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
             fsdp = True
         else:
             fsdp = _tp_param_bytes_per_chip(cfg, mesh) > 12e9
-    with use_abstract_mesh(mesh.abstract_mesh):
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         pshapes = params_specs(cfg)
         pshard = sh.params_shardings(cfg, mesh, fsdp=fsdp)
         ins = input_specs(cfg, shape)

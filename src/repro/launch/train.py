@@ -5,8 +5,8 @@ plan, against whatever devices are actually available:
 
   * on a real TPU slice this is the production launcher
     (``--mesh data,model`` sizes must multiply to the device count);
-  * on this CPU container it runs the REDUCED config end-to-end (the
-    ``--smoke`` path used by examples and CI).
+  * on a CPU it runs the REDUCED config end-to-end (the ``--smoke``
+    path used by examples and CI).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
@@ -24,7 +24,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch import sharding as sh
-from repro.launch.mesh import axis_type_kwargs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.frontend import frontend_embeddings
 from repro.train.checkpoint import save_checkpoint
@@ -73,13 +73,14 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    enable_compile_cache()
     ndev = len(jax.devices())
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))
     else:
         shape = (ndev, 1)
     mesh = jax.make_mesh(shape, ("data", "model"),
-                         **axis_type_kwargs(2))
+                         (jax.sharding.AxisType.Auto,) * 2)
     print(f"[train] {cfg.name}: mesh {dict(zip(mesh.axis_names, shape))} "
           f"on {ndev} device(s)")
 
@@ -89,7 +90,7 @@ def main(argv=None) -> int:
 
     pshard = sh.params_shardings(cfg, mesh, fsdp=ndev > 8)
     oshard = sh.opt_shardings(cfg, mesh, pshard)
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         params = jax.jit(
             lambda k: T.init_params(cfg, k),
             out_shardings=pshard)(jax.random.PRNGKey(0))

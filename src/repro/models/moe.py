@@ -30,14 +30,13 @@ from repro.models.layers import Params, dense_params, swiglu, swiglu_params
 
 
 from repro.models.shard_hints import constrain as _constrain
-from repro.models.shard_hints import get_abstract_mesh
 
 
 def _dispatch_groups(n: int) -> int:
     """Number of dispatch groups = ambient `data` axis size (1 if absent
     or indivisible)."""
-    mesh = get_abstract_mesh()
-    if mesh is None or "data" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "data" not in mesh.axis_names:
         return 1
     g = mesh.shape["data"]
     return g if n % g == 0 else 1

@@ -13,28 +13,9 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def get_abstract_mesh():
-    """`jax.sharding.get_abstract_mesh`, reaching into `jax._src.mesh` on
-    older releases (e.g. 0.4.x) where it is not yet public. Returns None
-    when unavailable so callers degrade to the unsharded no-op path."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as fn
-        except ImportError:
-            return None
-    try:
-        mesh = fn()
-    except Exception:
-        return None
-    # older jax returns internal context objects from the _src fallback;
-    # only a real (Abstract)Mesh with axis names is usable
-    return mesh if hasattr(mesh, "axis_names") else None
-
-
 def constrain(x, *axes):
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     spec = []
     for want, dim in zip(axes, x.shape):

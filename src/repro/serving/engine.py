@@ -81,12 +81,15 @@ from typing import Any, Callable, ClassVar, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import io_callback
+from jax.sharding import PartitionSpec as P
 
 from repro.core.cascade import (combine_escalated, escalation_capacity,
                                 gather_requests, select_escalations)
 from repro.core.supervisors import SOFTMAX_SUPERVISORS
 from repro.kernels.confidence_gate.ops import _on_tpu, confidence_gate
 from repro.kernels.fused_head_gate.ops import FusedLocalHead, fused_head_gate
+from repro.launch.mesh import batch_axes
 from repro.runtime.observability import (EV_BACKEND_AGREEMENT,
                                          EV_DEADLINE_DOWNGRADE,
                                          EV_POLICY_DOWNGRADE,
@@ -293,7 +296,7 @@ def make_local_step(local_apply: Callable, supervisor="max_softmax"):
 
 
 def make_gated_local_step(local_apply: Callable, supervisor="max_softmax",
-                          emit=None):
+                          emit=None, mesh=None):
     """Jit-able local tier fused with the confidence gate: supervisor
     scoring + thresholded ascending escalation ranking happen on device,
     and only the compact ``(conf [B], pred [B], idx [B])`` triple crosses
@@ -307,35 +310,44 @@ def make_gated_local_step(local_apply: Callable, supervisor="max_softmax",
     folded into the gate's scoring pass (kernels/fused_head_gate) so
     full-vocab logits never round-trip through HBM.
 
+    ``mesh`` makes the step data-parallel (DESIGN.md §12): under
+    ``shard_map`` each device runs the local forward and the scoring pass
+    on its row shard, and every device selects the escalation candidates
+    over the all-gathered ``[B]`` confidences, so ``idx`` holds global
+    row indices exactly as on one device. Parameters stay replicated.
+
     ``emit`` opts into in-kernel early emit (DESIGN.md §11): the step
     gains a trailing ``seq`` arg and the gate surfaces its triple to
     ``emit(seq, conf, pred, idx)`` on the host the moment it lands.
     """
-    fused = isinstance(local_apply, FusedLocalHead)
+    rows = None if mesh is None else batch_axes(mesh)
+    # data-parallel: select over every shard's rows, in global order
+    gather = (None if rows is None else
+              (lambda conf: jax.lax.all_gather(conf, rows, tiled=True)))
+
+    def gate(local_batch, t_local, n_valid):
+        if isinstance(local_apply, FusedLocalHead):
+            return fused_head_gate(local_apply.trunk(local_batch),
+                                   local_apply.w, local_apply.bias, t_local,
+                                   n_valid, supervisor=supervisor,
+                                   gather=gather)
+        return confidence_gate(local_apply(local_batch), t_local, n_valid,
+                               supervisor=supervisor, gather=gather)
+
+    if mesh is not None:
+        gate = jax.shard_map(
+            gate, mesh=mesh, in_specs=(P(rows), P(), P()),
+            out_specs={"conf": P(rows), "pred": P(rows), "idx": P()},
+            check_vma=False)
 
     if emit is None:
-        def step(local_batch, t_local, n_valid):
-            if fused:
-                h = local_apply.trunk(local_batch)
-                return fused_head_gate(h, local_apply.w, local_apply.bias,
-                                       t_local, n_valid,
-                                       supervisor=supervisor)
-            logits = local_apply(local_batch)
-            return confidence_gate(logits, t_local, n_valid,
-                                   supervisor=supervisor)
-
-        return step
+        return gate
 
     def step(local_batch, t_local, n_valid, seq):
-        if fused:
-            h = local_apply.trunk(local_batch)
-            return fused_head_gate(h, local_apply.w, local_apply.bias,
-                                   t_local, n_valid, supervisor=supervisor,
-                                   emit=emit, emit_tag=seq)
-        logits = local_apply(local_batch)
-        return confidence_gate(logits, t_local, n_valid,
-                               supervisor=supervisor, emit=emit,
-                               emit_tag=seq)
+        out = gate(local_batch, t_local, n_valid)
+        io_callback(emit, None, jnp.asarray(seq, jnp.int32), out["conf"],
+                    out["pred"], out["idx"], ordered=False)
+        return out
 
     return step
 
@@ -522,8 +534,13 @@ class CascadeEngine:
         # set by any window's remote future resolving (any backend): the
         # streaming drain parks here instead of polling head-of-line
         self._ready = threading.Event()
-        self._supervisor = (supervisor if callable(supervisor)
-                            else SOFTMAX_SUPERVISORS[supervisor])
+        sup = (supervisor if callable(supervisor)
+               else SOFTMAX_SUPERVISORS[supervisor])
+        # 2nd-level scoring of remote logits: one jitted program over
+        # rows padded to batch_size (_score_remote), so a window's
+        # escalation count never triggers a compile
+        self._remote_scorer = jax.jit(
+            lambda lg: (sup(lg).astype(jnp.float32), jnp.argmax(lg, -1)))
         # observability facade (DESIGN.md §9): None = disabled; install()
         # wires the router/transports/controller into the shared event
         # log and registers the snapshot-time metrics collector
@@ -548,23 +565,22 @@ class CascadeEngine:
         self._gate_emits = 0            # telemetry: callbacks landed
         self._gate_lock = threading.Lock()
         self._gate_results: dict[int, tuple] = {}
-        # data-parallel local forward (DESIGN.md §12): when a mesh is
-        # supplied the gated local step constrains its input batch to
-        # batch-dim sharding before jit — parameters stay replicated.
-        # On a 1-device mesh the constraint is a no-op, so enabling it
-        # never changes predictions.
+        # data-parallel local forward (DESIGN.md §12): with a mesh the
+        # gated local step scores each row shard on its own device and
+        # selects over the gathered confidences — parameters stay
+        # replicated and the triple equals the one-device triple
         self.mesh = mesh
+        if mesh is not None and batch_size % mesh.size:
+            raise ValueError(f"batch_size {batch_size} must divide over "
+                             f"the {mesh.size}-device serving mesh")
         if transport is None:
             self._step = jax.jit(make_cascade_step(
                 local_apply, remote_apply, self.capacity, supervisor))
         else:
-            step = make_gated_local_step(
+            self._local_step = jax.jit(make_gated_local_step(
                 local_apply, supervisor,
-                emit=self._on_gate if self.early_emit else None)
-            if mesh is not None:
-                from repro.launch.sharding import shard_local_step
-                step = shard_local_step(step, mesh)
-            self._local_step = jax.jit(step)
+                emit=self._on_gate if self.early_emit else None,
+                mesh=mesh))
 
     # -- ServeConfig construction (DESIGN.md §8) -----------------------
     _UNSET = object()
@@ -626,6 +642,32 @@ class CascadeEngine:
             eng.set_local_threshold(config.t_local)
         return eng
 
+    def warmup(self, local_batch, remote_classes: int,
+               remote_batch=None) -> str:
+        """Compile and run every program the serve loop dispatches: the
+        gated local step on ``local_batch`` (a full ``batch_size`` window
+        of local inputs) and the 2nd-level scoring of ``remote_classes``-
+        wide remote logits. The local dispatch is seq 0, which is no
+        window: its early-emit callback is dropped. Returns the compiled
+        local step's HLO text (a Pallas gate shows up as
+        ``tpu_custom_call``). The fused path has one program, the cascade
+        step over ``{"local": local_batch, "remote": remote_batch}``."""
+        if self.transport is None:
+            if remote_batch is None:
+                raise ValueError("the fused cascade step needs a "
+                                 "remote_batch to compile on")
+            batch = {"local": local_batch, "remote": remote_batch}
+            hlo = self._step.lower(batch).compile().as_text()
+            jax.block_until_ready(self._step(batch))
+            return hlo
+        args = (local_batch, np.float32(np.inf), np.int32(0))
+        if self.early_emit:
+            args += (np.int32(0),)
+        hlo = self._local_step.lower(*args).compile().as_text()
+        jax.block_until_ready(self._local_step(*args))
+        self._score_remote([np.zeros((remote_classes,), np.float32)])
+        return hlo
+
     def set_remote_threshold(self, t: float) -> None:
         """Runtime reconfiguration (paper §4.5)."""
         self.t_remote = t
@@ -640,6 +682,8 @@ class CascadeEngine:
         window ``seq`` just landed on the host. Runs whenever the device
         forces the computation — possibly on a transport thread — so it
         only stores and signals; consumers poll ``gate_result``."""
+        if int(seq) == 0:               # the warm-up dispatch, no window
+            return
         with self._gate_lock:
             self._gate_results[int(seq)] = (np.asarray(conf).copy(),
                                             np.asarray(pred).copy(),
@@ -1124,9 +1168,7 @@ class CascadeEngine:
                if j not in fl.forced and fl.cached[j] is not None]
         if not hit:
             return
-        rlogits = jnp.asarray(np.stack([fl.cached[j] for j in hit]))
-        rconf = np.asarray(self._supervisor(rlogits))
-        rpred = np.asarray(jnp.argmax(rlogits, -1))
+        rconf, rpred = self._score_remote([fl.cached[j] for j in hit])
         for w, j in enumerate(hit):
             i = int(fl.idx[j])
             accepted = bool(rconf[w] > self.t_remote)
@@ -1139,6 +1181,17 @@ class CascadeEngine:
                             else UNATTRIBUTED),
                 "cost": 0.0,
             })
+
+    def _score_remote(self, rows: list) -> tuple[np.ndarray, np.ndarray]:
+        """2nd-level supervisor confidence and argmax of remote logits
+        rows, scored as one ``[batch_size, C]`` program (zero rows pad
+        the tail; escalations never exceed the batch)."""
+        n = len(rows)
+        lg = np.zeros((max(self.batch_size, n),) + np.shape(rows[0]),
+                      np.float32)
+        lg[:n] = rows
+        conf, pred = jax.device_get(self._remote_scorer(lg))
+        return conf[:n], pred[:n]
 
     # -- runtime path: finalize half -----------------------------------
     def _finalize(self, fl: _InFlight) -> None:
@@ -1193,9 +1246,7 @@ class CascadeEngine:
             n_hits = fl.k - len(fl.miss) - len(fl.forced)
             got = [j for j, c in enumerate(cached) if c is not None]
             if got:
-                rlogits = jnp.asarray(np.stack([cached[j] for j in got]))
-                rconf = np.asarray(self._supervisor(rlogits))
-                rpred = np.asarray(jnp.argmax(rlogits, -1))
+                rconf, rpred = self._score_remote([cached[j] for j in got])
                 remote_conf[fl.idx[got]] = rconf
                 fl.pred[fl.idx[got]] = rpred
             failed = [j for j, c in enumerate(cached) if c is None]

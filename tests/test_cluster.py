@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -355,13 +356,22 @@ def test_serveconfig_cluster_validation():
 
 
 def test_data_parallel_shard_is_numeric_noop():
+    """The data-parallel gated step (shard_map over the serving mesh,
+    selection over the gathered confidences) returns the one-device
+    triple bit for bit."""
     from repro.launch.mesh import make_serving_mesh
-    from repro.launch.sharding import shard_local_step
+    from repro.serving.engine import make_gated_local_step
     mesh = make_serving_mesh()
+    w = jnp.linspace(-1, 1, 4 * 8).reshape(4, 8)
 
-    def step(x):
-        return jnp.tanh(x) * 2.0
+    def local_apply(x):
+        return jnp.tanh(x) @ w * 3.0
 
     x = jnp.linspace(-1, 1, 32).reshape(8, 4)
-    np.testing.assert_allclose(np.asarray(shard_local_step(step, mesh)(x)),
-                               np.asarray(step(x)), rtol=0, atol=0)
+    t, n = np.float32(0.6), np.int32(7)
+    want = jax.jit(make_gated_local_step(local_apply))(x, t, n)
+    got = jax.jit(make_gated_local_step(local_apply, mesh=mesh))(x, t, n)
+    for key in ("conf", "pred", "idx"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), key)
+    assert (np.asarray(want["idx"]) >= 0).any()

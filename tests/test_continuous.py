@@ -9,11 +9,13 @@ the ``/metrics`` scrape endpoint and the ``ServeConfig`` plumbing."""
 from __future__ import annotations
 
 import json
+import logging
 import threading
 import time
 import urllib.error
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -251,6 +253,40 @@ def test_forced_early_emit_matches_window_and_sweeps():
     assert e_con._gate_results == {}        # swept at commit
     e_win.close()
     e_con.close()
+
+
+def test_warmup_compiles_every_loop_program_and_counts_no_window():
+    """``warmup`` compiles the gated local step and the 2nd-level remote
+    scoring before serving: the serve loop then compiles nothing, and
+    the warm-up dispatch (seq 0) lands no early-emit window."""
+    rng = np.random.default_rng(9)
+    xs, _ = make_stream(rng, 48)
+    engine = CascadeEngine(local_apply, batch_size=8,
+                           remote_fraction_budget=0.5, t_remote=0.0,
+                           transport=RemoteTransport(remote_apply,
+                                                     quiet_tconf()),
+                           early_emit=True)
+    sched = MicrobatchScheduler(engine, fallback=lambda r: -7,
+                                pipeline_depth=4,
+                                completion_mode="streaming",
+                                batching="continuous")
+    hlo = engine.warmup(xs[:8], remote_classes=xs.shape[1])
+    assert "HloModule" in hlo
+    assert engine._gate_emits == 0 and engine._gate_results == {}
+    compiles = []
+    with jax.log_compiles(True):
+        handler = logging.Handler()
+        handler.emit = lambda rec: compiles.append(rec.getMessage())
+        log = logging.getLogger("jax")
+        log.addHandler(handler)
+        try:
+            responses = serve_all(sched, xs)
+        finally:
+            log.removeHandler(handler)
+    assert len(responses) == 48
+    assert engine._gate_emits == 48 // 8
+    assert not [m for m in compiles if m.startswith("Compiling")], compiles
+    engine.close()
 
 
 def test_continuous_fused_local_head_matches_window():

@@ -1,6 +1,7 @@
 """Pallas kernel validation: every kernel is swept over shapes/dtypes and
 asserted allclose against its ref.py pure-jnp oracle, with the kernel body
-executed in interpret mode (CPU container; TPU v5e is the compile target)."""
+executed in interpret mode on the CPU. tests/test_tpu_compile.py compiles
+the serve path's gate kernels for a described TPU v5e."""
 
 from __future__ import annotations
 
@@ -109,8 +110,18 @@ def test_confidence_gate_extreme_logits_stable():
         assert bool(jnp.all(jnp.isfinite(got["conf"]))), sup
 
 
-def test_confidence_gate_callable_supervisor_falls_back():
-    """Callable supervisors (paper §4.2) take the jnp path everywhere."""
+def test_confidence_gate_callable_supervisor_falls_back(monkeypatch):
+    """Callable supervisors (paper §4.2) take the jnp path everywhere:
+    scoring and selection, even where the backend is a TPU."""
+    from repro.kernels.confidence_gate import ops as gate_ops
+
+    def no_kernel(*a, **kw):
+        raise AssertionError("a callable supervisor reached a Pallas kernel")
+
+    monkeypatch.setattr(gate_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gate_ops, "gate_scores_pallas", no_kernel)
+    monkeypatch.setattr(gate_ops, "select_pallas", no_kernel)
+
     def margin(logits):
         top2 = jax.lax.top_k(logits, 2)[0]
         return top2[..., 0] - top2[..., 1]
@@ -125,9 +136,11 @@ def test_confidence_gate_callable_supervisor_falls_back():
 
 
 def test_confidence_gate_early_emit_fires_inside_jit():
-    """The early-emit host callback (ISSUE 8) must fire exactly once per
-    gate call from INSIDE a jitted computation, tagged with the dispatch
-    seq and carrying the same conf/pred/idx the gate returns."""
+    """The early-emit host callback of the gated local step must fire
+    exactly once per gate call from INSIDE a jitted computation, tagged
+    with the dispatch seq and carrying the same conf/pred/idx the gate
+    returns."""
+    from repro.serving.engine import make_gated_local_step
     logits = rnd(jax.random.fold_in(KEY, 7), (8, 64), scale=4.0)
     fired = []
 
@@ -135,9 +148,8 @@ def test_confidence_gate_early_emit_fires_inside_jit():
         fired.append((int(tag), np.asarray(pred).copy(),
                       np.asarray(idx).copy()))
 
-    out = jax.jit(lambda x: confidence_gate(
-        x, 0.5, supervisor="max_softmax", k=4, emit=emit,
-        emit_tag=11))(logits)
+    out = jax.jit(make_gated_local_step(lambda x: x, emit=emit))(
+        logits, 0.5, 8, 11)
     jax.block_until_ready(out["pred"])
     assert len(fired) == 1
     tag, pred, idx = fired[0]
