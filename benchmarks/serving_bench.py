@@ -474,7 +474,6 @@ def run(verbose: bool = True, requests: int = 1024, depth: int = 8,
                 "peak_occupied": slots.peak,
                 "joins": slots.joins,
                 "leaves": slots.leaves,
-                "occupancy_ema": slots.occupancy_ema,
             },
             **split_cont,
             "checks": cont_checks,

@@ -190,6 +190,7 @@ def gate_scores_pallas(logits: jnp.ndarray, *, supervisor: str,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="gate_scores_pallas",        # the device trace's op name
     )(logits)
     return conf[:, 0], pred[:, 0]
 
@@ -212,5 +213,6 @@ def select_pallas(conf: jnp.ndarray, t_local: jnp.ndarray,
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((k,), jnp.int32),
         interpret=interpret,
+        name="select_pallas",             # the device trace's op name
     )(jnp.asarray(t_local, jnp.float32).reshape(1),
       jnp.asarray(n_valid, jnp.int32).reshape(1), conf_row)

@@ -88,5 +88,6 @@ def head_gate_scores_pallas(hidden: jnp.ndarray, w: jnp.ndarray,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="head_gate_scores_pallas",   # the device trace's op name
     )(hidden, w, bias.reshape(1, v))
     return conf[:, 0], pred[:, 0]

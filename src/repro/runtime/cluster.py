@@ -710,10 +710,13 @@ class ClusterHarness:
                 name, engine, sched, controller, view, _Worker(name))
         # per-replica installs each re-pointed the shared router at
         # their labelled proxy (last one wins) — the router and its
-        # transports are fleet-scope, so re-attach the raw log
+        # transports are fleet-scope, so re-attach the raw log, keeping
+        # the span factory the installs wired in
         if self.events is not None and self.router is not None \
                 and hasattr(self.router, "attach_events"):
-            self.router.attach_events(self.events)
+            self.router.attach_events(self.events,
+                                      span=getattr(self.router, "span",
+                                                   None))
         self._closed = False
 
     # -- driving -------------------------------------------------------

@@ -35,6 +35,12 @@ Three components behind one ``Observability`` facade:
   number (the ordering contract — emitters live on engine and pool
   threads), a monotonic timestamp, and the window id that triggered it.
 
+Beside them, ``Observability.span`` opens a ``jax.profiler``
+``TraceAnnotation`` at each place the serving thread works or waits
+(``SPAN_NAMES``), so a profiler trace shows the program's own phases on
+the device trace's clock; while no profiler runs a span records nothing.
+Call sites enter the shared ``NULL_SPAN`` when observability is off.
+
 Span stage glossary, metric names and the event schema are tabulated in
 DESIGN.md §9; the future chaos bench asserts against the trace/event
 output as ground truth.
@@ -42,6 +48,7 @@ output as ground truth.
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
@@ -71,6 +78,8 @@ __all__ = [
     "EV_STAGE_ANSWER",
     "EV_TIER_RECONCILE",
     "LATENCY_BUCKETS_S",
+    "NULL_SPAN",
+    "SPAN_NAMES",
     "SPAN_STAGES",
     "Counter",
     "EventLog",
@@ -126,6 +135,13 @@ EV_TIER_RECONCILE = "tier_reconcile"
 # gate time by the in-kernel early emit, ahead of its window's commit
 SPAN_STAGES = ("enqueue", "pack", "join", "dispatch", "gate", "route",
                "cache_hit", "remote", "commit", "emit", "handback")
+
+# profiler spans (DESIGN.md §9 span table): each a leaf — none encloses
+# both a wait and work, so a device idle gap is named by what the host
+# was doing in it
+SPAN_NAMES = ("scheduler.admit", "cascade.gate", "cascade.route",
+              "cascade.remote_wait", "cascade.complete", "scheduler.handback",
+              "cascade.early_emit", "transport.call", "python.gc")
 
 # fixed histogram buckets for latency-shaped observations (seconds);
 # +inf is implicit (the _count line covers it)
@@ -427,6 +443,48 @@ class TraceSink:
         return len(events)
 
 
+class _NullSpan:
+    """The span a call site enters while observability is off: one shared
+    instance, so the disabled path builds no object per window."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
+
+    def set_metadata(self, **attrs: Any) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: one ``python.gc`` span per collection while
+    a profiler runs. It holds no reference to the engine, so a hook left
+    installed keeps nothing else alive."""
+
+    __slots__ = ("_annotation", "_open")
+
+    def __init__(self) -> None:
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if self._annotation.is_enabled():
+                self._open = self._annotation(
+                    "python.gc", generation=info["generation"])
+                self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
 class Observability:
     """Facade bundling the metrics registry, trace sink and event log.
 
@@ -444,6 +502,7 @@ class Observability:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.trace = trace
         self.events = events if events is not None else EventLog(clock=clock)
+        self._gc_spans: _GcSpans | None = None
 
     @classmethod
     def enabled(cls, *, trace_capacity: int = 65536,
@@ -456,24 +515,45 @@ class Observability:
                    events=EventLog(event_capacity, clock=clock),
                    clock=clock)
 
+    @staticmethod
+    def span(name: str, **attrs: Any) -> Any:
+        """A profiler span (``jax.profiler.TraceAnnotation``) named
+        ``name`` from ``SPAN_NAMES``, with ``attrs`` as its arguments;
+        ``set_metadata`` adds arguments known only inside it. It records
+        only while a profiler trace runs."""
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation(name, **attrs)
+
     # -- wiring ---------------------------------------------------------
     def install(self, engine: Any) -> "Observability":
         """Attach to a ``CascadeEngine`` (runtime path): the engine
         stamps window stages and publishes commit-time counters; every
         backend transport, the router and the controller emit their
-        state transitions into the shared event log; derived gauges are
-        registered as snapshot-time collectors over the live stats."""
+        state transitions into the shared event log, and the transports
+        open ``transport.call`` spans; derived gauges are registered as
+        snapshot-time collectors over the live stats; garbage
+        collections show as ``python.gc`` spans until ``close``."""
         engine.observability = self
         # one clock everywhere: event timestamps become comparable with
         # span stage stamps (ordering across threads still uses seq)
         self.events._clock = engine._clock
         if engine.router is not None:
-            engine.router.attach_events(self.events)
+            engine.router.attach_events(self.events, span=self.span)
         if engine.controller is not None:
             engine.controller.events = self.events
         self.metrics.register_collector(
             lambda reg: _collect_engine(reg, engine))
+        if self._gc_spans is None:
+            self._gc_spans = _GcSpans()
+            gc.callbacks.append(self._gc_spans)
         return self
+
+    def close(self) -> None:
+        """Remove the ``python.gc`` hook (``CascadeEngine.close`` calls
+        this). Idempotent."""
+        if self._gc_spans is not None:
+            gc.callbacks.remove(self._gc_spans)
+            self._gc_spans = None
 
 
 def _collect_engine(reg: MetricsRegistry, engine: Any) -> None:
@@ -487,8 +567,6 @@ def _collect_engine(reg: MetricsRegistry, engine: Any) -> None:
         reg.gauge("cascade_escalation_fraction").set(st.escalation_fraction)
         reg.gauge("cascade_remote_fraction").set(st.remote_fraction)
     reg.gauge("cascade_mean_modelled_latency_seconds").set(st.mean_latency_s)
-    reg.gauge("cascade_mean_wall_latency_seconds").set(st.mean_wall_latency_s)
-    reg.gauge("cascade_p95_wall_latency_seconds").set(st.wall_percentile(95))
     if engine.router is not None:
         rs = engine.router.stats
         reg.gauge("router_failovers").set(rs.failovers)

@@ -83,6 +83,7 @@ from .observability import (
     EV_REPLAY_SERVED,
     EV_ROUTER_FAILBACK,
     EV_ROUTER_FAILOVER,
+    NULL_SPAN,
 )
 
 
@@ -304,9 +305,11 @@ class RemoteTransport:
         # observability (DESIGN.md §9): an EventLog installed by the
         # Observability facade; None = disabled, every hook short-circuits
         # on one attribute test. ``event_source`` is the backend name the
-        # router wires in (a bare transport reports as "remote").
+        # router wires in (a bare transport reports as "remote"). ``span``
+        # is the facade's span factory, wired in beside the event log.
         self.events: Any = None
         self.event_source = "remote"
+        self.span: Callable[..., Any] | None = None
 
     _BREAKER_EVENTS: ClassVar[dict] = {OPEN: EV_BREAKER_OPEN,
                                        HALF_OPEN: EV_BREAKER_HALF_OPEN,
@@ -424,9 +427,16 @@ class RemoteTransport:
     def call(self, batch: Any, tag: int | None = None
              ) -> tuple[np.ndarray | None, np.ndarray]:
         n = _rows(batch)
+        w = max(1, self.config.max_in_flight)
+        with (self.span("transport.call", rows=n, windows=-(-n // w))
+              if self.span is not None else NULL_SPAN):
+            return self._call(batch, n, w, tag)
+
+    def _call(self, batch: Any, n: int, w: int, tag: int | None
+              ) -> tuple[np.ndarray | None, np.ndarray]:
+        """The windows of one ``call``, one after another."""
         ok = np.zeros((n,), bool)
         outs: list[tuple[int, np.ndarray]] = []
-        w = max(1, self.config.max_in_flight)
         for lo in range(0, n, w):
             hi = min(lo + w, n)
             with self._lock:
@@ -696,6 +706,7 @@ class RemoteRouter:
         # whether routing has drifted off the policy-preferred backend so
         # the return to it is emitted as one fail-back event.
         self.events: Any = None
+        self.span: Callable[..., Any] | None = None
         self._failed_over = False
 
     def __len__(self) -> int:
@@ -704,15 +715,20 @@ class RemoteRouter:
     def __iter__(self):
         return iter(self.backends)
 
-    def attach_events(self, events: Any) -> None:
+    def attach_events(self, events: Any,
+                      span: Callable[..., Any] | None = None) -> None:
         """Wire this router and every backend transport into one event
-        log. Idempotent; the Observability facade calls it at install
-        time, and the cluster harness re-points a shared router at the
-        raw fleet-level log after per-replica installs (DESIGN.md §12)."""
+        log, and give the transports the facade's span factory (None:
+        no ``transport.call`` spans). Idempotent; the Observability
+        facade calls it at install time, and the cluster harness
+        re-points a shared router at the raw fleet-level log after
+        per-replica installs (DESIGN.md §12)."""
         self.events = events
+        self.span = span
         for b in self.backends:
             b.transport.events = events
             b.transport.event_source = b.name
+            b.transport.span = span
 
     def backend(self, name: str) -> RemoteBackend:
         for b in self.backends:

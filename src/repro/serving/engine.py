@@ -93,7 +93,7 @@ from repro.launch.mesh import batch_axes
 from repro.runtime.observability import (EV_BACKEND_AGREEMENT,
                                          EV_DEADLINE_DOWNGRADE,
                                          EV_POLICY_DOWNGRADE,
-                                         EV_STAGE_ANSWER)
+                                         EV_STAGE_ANSWER, NULL_SPAN)
 from repro.runtime.transport import (RemoteBackend, RemoteRouter,
                                      RouteConstraint)
 from repro.serving.policy import (CACHED, DEADLINE_LOCAL, LOCAL,
@@ -325,14 +325,21 @@ def make_gated_local_step(local_apply: Callable, supervisor="max_softmax",
     gather = (None if rows is None else
               (lambda conf: jax.lax.all_gather(conf, rows, tiled=True)))
 
+    fused = isinstance(local_apply, FusedLocalHead)
+
     def gate(local_batch, t_local, n_valid):
-        if isinstance(local_apply, FusedLocalHead):
-            return fused_head_gate(local_apply.trunk(local_batch),
-                                   local_apply.w, local_apply.bias, t_local,
-                                   n_valid, supervisor=supervisor,
-                                   gather=gather)
-        return confidence_gate(local_apply(local_batch), t_local, n_valid,
-                               supervisor=supervisor, gather=gather)
+        # stable names for the device trace: the trunk's ops and the
+        # gate's kernels carry these scopes in their op metadata
+        with jax.named_scope("trunk"):
+            x = (local_apply.trunk(local_batch) if fused
+                 else local_apply(local_batch))
+        with jax.named_scope("gate"):
+            if fused:
+                return fused_head_gate(x, local_apply.w, local_apply.bias,
+                                       t_local, n_valid,
+                                       supervisor=supervisor, gather=gather)
+            return confidence_gate(x, t_local, n_valid,
+                                   supervisor=supervisor, gather=gather)
 
     if mesh is not None:
         gate = jax.shard_map(
@@ -426,7 +433,8 @@ class _InFlight:
     pending: Any = None         # TransportFuture | _Resolved | None
     backend: Any = None         # RemoteBackend routed to (None = unrouted)
     replay_ticket: bool = False # parked for a bounded (unrouted) replay
-    sub_miss: Any = None        # miss sub-batch, held only for a replay
+    sub_miss: Any = None        # miss sub-batch, held for a synchronous
+    #                             call or a replay until the finalize half
     # -- finalize half --------------------------------------------------
     finalized: bool = False
     result: dict | None = None
@@ -684,12 +692,15 @@ class CascadeEngine:
         only stores and signals; consumers poll ``gate_result``."""
         if int(seq) == 0:               # the warm-up dispatch, no window
             return
-        with self._gate_lock:
-            self._gate_results[int(seq)] = (np.asarray(conf).copy(),
-                                            np.asarray(pred).copy(),
-                                            np.asarray(idx).copy())
-            self._gate_emits += 1
-        self._ready.set()
+        obs = self.observability
+        with (obs.span("cascade.early_emit", window=int(seq))
+              if obs is not None else NULL_SPAN):
+            with self._gate_lock:
+                self._gate_results[int(seq)] = (np.asarray(conf).copy(),
+                                                np.asarray(pred).copy(),
+                                                np.asarray(idx).copy())
+                self._gate_emits += 1
+            self._ready.set()
 
     def gate_result(self, seq: int):
         """The early-emitted gate triple for window ``seq`` (``(conf,
@@ -816,7 +827,10 @@ class CascadeEngine:
                 return events
             # event wakeup from any backend's pool; the timeout is a
             # safety net, not a poll interval
-            self._ready.wait(0.05)
+            obs = self.observability
+            with (obs.span("cascade.remote_wait", inflight=len(self._inflight))
+                  if obs is not None else NULL_SPAN):
+                self._ready.wait(0.05)
 
     def stream(self) -> Iterator[tuple[int, dict[str, np.ndarray]]]:
         """Generator draining every in-flight window in completion order
@@ -951,31 +965,34 @@ class CascadeEngine:
         escalations' cache/routing/transport submission (DESIGN.md
         §11)."""
         emitted = self.gate_result(fl.seq) if self.early_emit else None
-        if emitted is not None:
-            # the in-kernel emit already landed this window's triple on
-            # the host — reuse it instead of a second device fetch
-            conf, pred, cand = emitted
-            fl.conf = np.asarray(conf)
-            fl.local_pred = np.asarray(pred)
-        else:
-            gate = jax.device_get(fl.gate_dev)
-            fl.conf = np.asarray(gate["conf"])
-            fl.local_pred = np.asarray(gate["pred"])
-            cand = gate["idx"]
-        fl.gate_dev = None
-        fl.pred = fl.local_pred.copy()
-        cand = np.asarray(cand)
-        cand = cand[cand >= 0]          # eligible rows, ascending by conf
-        fl.k = int(min(cand.size, fl.capacity, fl.real))
-        fl.idx = cand[:fl.k]
-        if fl.tr is not None:
-            fl.tr["gate"] = self._clock()
-
-        if fl.policed:
-            # per-request policy pass (DESIGN.md §8): escalation
-            # overrides, cost-cap and deadline-vs-EMA feasibility — may
-            # shrink/extend fl.idx and record downgrades/forced rejects
-            self._apply_policies(fl)
+        obs = self.observability
+        with (obs.span("cascade.gate", window=fl.seq,
+                       source="fetch" if emitted is None else "emit")
+              if obs is not None else NULL_SPAN):
+            if emitted is not None:
+                # the in-kernel emit already landed this window's triple on
+                # the host — reuse it instead of a second device fetch
+                conf, pred, cand = emitted
+                fl.conf = np.asarray(conf)
+                fl.local_pred = np.asarray(pred)
+            else:
+                gate = jax.device_get(fl.gate_dev)
+                fl.conf = np.asarray(gate["conf"])
+                fl.local_pred = np.asarray(gate["pred"])
+                cand = gate["idx"]
+            fl.gate_dev = None
+            fl.pred = fl.local_pred.copy()
+            cand = np.asarray(cand)
+            cand = cand[cand >= 0]          # eligible rows, ascending by conf
+            fl.k = int(min(cand.size, fl.capacity, fl.real))
+            fl.idx = cand[:fl.k]
+            if fl.policed:
+                # per-request policy pass (DESIGN.md §8): escalation
+                # overrides, cost-cap and deadline-vs-EMA feasibility — may
+                # shrink/extend fl.idx and record downgrades/forced rejects
+                self._apply_policies(fl)
+            if fl.tr is not None:
+                fl.tr["gate"] = self._clock()
         fl.gate_done = True
 
     def _host_begin(self, fl: _InFlight) -> None:
@@ -985,60 +1002,69 @@ class CascadeEngine:
         if not fl.gate_done:
             self._host_gate(fl)
         if fl.k > 0:
-            host = jax.tree.map(np.asarray, fl.remote_batch)
-            sub = jax.tree.map(lambda a: a[fl.idx], host)  # batched gather
-            if self.cache is not None:
-                fl.keys = self.cache.keys_for(sub, fl.k)
-                # policy-REJECTED rows never consult cache or transport
-                found = [None if j in fl.forced else self.cache.lookup(key)
-                         for j, key in enumerate(fl.keys)]
-                fl.cached = [f[0] if f is not None else None for f in found]
-                fl.hit_src = [f[1] if f is not None else None for f in found]
-            else:
-                fl.keys = [None] * fl.k
-                fl.cached = [None] * fl.k
-                fl.hit_src = [None] * fl.k
-            fl.miss = [j for j, c in enumerate(fl.cached)
-                       if c is None and j not in fl.forced]
-            if fl.miss:
-                # route the window at submit time; an open breaker fails
-                # over to the next policy candidate immediately. The
-                # merged RouteConstraint (cost cap / remaining deadline /
-                # hint) narrows the candidate set (DESIGN.md §8)
-                fl.backend = self.router.pick(self._window_constraint(fl),
-                                              window=fl.seq)
-                marr = np.asarray(fl.miss)
-                sub_miss = jax.tree.map(lambda a: a[marr], sub)
-                if fl.backend is not None:
-                    fl.pending = (fl.backend.submit(sub_miss, fl.seq)
-                                  if fl.asynchronous
-                                  else _Resolved(
-                                      fl.backend.call(sub_miss, fl.seq)))
-                    if fl.asynchronous:
-                        # ready-set wakeup for the streaming drain
-                        fl.pending.add_done_callback(
-                            lambda _f: self._ready.set())
-                elif (fl.asynchronous
-                      and self.router.acquire_replay_slot(window=fl.seq)):
-                    # every breaker refused: park the window with a
-                    # bounded replay ticket — redeemed at its drain, when
-                    # a breaker may have half-opened (DESIGN.md §7). The
-                    # sync path finalizes immediately, so a ticket there
-                    # could never be served — don't burn a slot on it
-                    fl.replay_ticket = True
-                    fl.sub_miss = sub_miss
-            if (fl.asynchronous and self.early_handback
-                    and self.controller is None):
-                # cache hits are fully decidable now (static t_remote):
-                # expose them so the streaming scheduler hands them back
-                # with the trusted locals instead of after the window's
-                # remote drain (DESIGN.md §8; the finalize half still
-                # recomputes, keeping FIFO results untouched)
-                self._early_decide(fl)
-        if fl.tr is not None and fl.k > 0:
-            fl.tr["route"] = self._clock()
+            obs = self.observability
+            with (obs.span("cascade.route", window=fl.seq)
+                  if obs is not None else NULL_SPAN) as span:
+                self._route_escalations(fl)
+                span.set_metadata(misses=len(fl.miss))
+                if fl.tr is not None:
+                    fl.tr["route"] = self._clock()
         fl.remote_batch = None
         fl.host_done = True
+
+    def _route_escalations(self, fl: _InFlight) -> None:
+        """Gather the escalated rows, look them up in the cache, route the
+        misses to a backend and submit them (the synchronous path calls
+        at its finalize half, so the route holds no remote wait)."""
+        host = jax.tree.map(np.asarray, fl.remote_batch)
+        sub = jax.tree.map(lambda a: a[fl.idx], host)  # batched gather
+        if self.cache is not None:
+            fl.keys = self.cache.keys_for(sub, fl.k)
+            # policy-REJECTED rows never consult cache or transport
+            found = [None if j in fl.forced else self.cache.lookup(key)
+                     for j, key in enumerate(fl.keys)]
+            fl.cached = [f[0] if f is not None else None for f in found]
+            fl.hit_src = [f[1] if f is not None else None for f in found]
+        else:
+            fl.keys = [None] * fl.k
+            fl.cached = [None] * fl.k
+            fl.hit_src = [None] * fl.k
+        fl.miss = [j for j, c in enumerate(fl.cached)
+                   if c is None and j not in fl.forced]
+        if fl.miss:
+            # route the window at submit time; an open breaker fails
+            # over to the next policy candidate immediately. The
+            # merged RouteConstraint (cost cap / remaining deadline /
+            # hint) narrows the candidate set (DESIGN.md §8)
+            fl.backend = self.router.pick(self._window_constraint(fl),
+                                          window=fl.seq)
+            marr = np.asarray(fl.miss)
+            sub_miss = jax.tree.map(lambda a: a[marr], sub)
+            if fl.backend is not None and not fl.asynchronous:
+                # the synchronous call waits at the finalize half
+                fl.sub_miss = sub_miss
+            elif fl.backend is not None:
+                fl.pending = fl.backend.submit(sub_miss, fl.seq)
+                # ready-set wakeup for the streaming drain
+                fl.pending.add_done_callback(
+                    lambda _f: self._ready.set())
+            elif (fl.asynchronous
+                  and self.router.acquire_replay_slot(window=fl.seq)):
+                # every breaker refused: park the window with a
+                # bounded replay ticket — redeemed at its drain, when
+                # a breaker may have half-opened (DESIGN.md §7). The
+                # sync path finalizes immediately, so a ticket there
+                # could never be served — don't burn a slot on it
+                fl.replay_ticket = True
+                fl.sub_miss = sub_miss
+        if (fl.asynchronous and self.early_handback
+                and self.controller is None):
+            # cache hits are fully decidable now (static t_remote):
+            # expose them so the streaming scheduler hands them back
+            # with the trusted locals instead of after the window's
+            # remote drain (DESIGN.md §8; the finalize half still
+            # recomputes, keeping FIFO results untouched)
+            self._early_decide(fl)
 
     # -- per-request policy layer (DESIGN.md §8) -----------------------
     def _policy_for(self, fl: _InFlight, i: int) -> RequestPolicy | None:
@@ -1203,25 +1229,47 @@ class CascadeEngine:
             return
         if not fl.host_done:
             self._host_begin(fl)
+        answer = self._await_remote(fl)
+        obs = self.observability
+        with (obs.span("cascade.complete", window=fl.seq)
+              if obs is not None else NULL_SPAN):
+            self._fold_remote(fl, answer)
+
+    def _await_remote(self, fl: _InFlight):
+        """The window's remote answer ``(logits, ok)``, or None when no
+        backend took its misses. Makes the synchronous path's call, or
+        redeems a replay ticket, here; waits out a future that has not
+        landed under ``cascade.remote_wait``."""
+        if not (fl.k > 0 and fl.miss):
+            return None
+        if fl.pending is None:
+            if fl.replay_ticket:
+                # (unrouted) replay (DESIGN.md §7): one more pick at
+                # drain time — a breaker that half-opened while the
+                # window rode the pipeline serves it (the call IS the
+                # half-open probe), billed to the replaying backend
+                fl.replay_ticket = False
+                fl.backend = self.router.redeem_replay(
+                    self._window_constraint(fl), window=fl.seq)
+            if fl.backend is not None and fl.sub_miss is not None:
+                fl.pending = _Resolved(fl.backend.call(fl.sub_miss, fl.seq))
+            fl.sub_miss = None
+            if fl.pending is None:
+                return None
+        obs = self.observability
+        with (obs.span("cascade.remote_wait", inflight=len(self._inflight))
+              if obs is not None and not fl.pending.done() else NULL_SPAN):
+            return fl.pending.result()
+
+    def _fold_remote(self, fl: _InFlight, answer) -> None:
+        """The finalize half's work once the remote ``answer`` is in."""
         remote_conf = np.full((fl.b,), np.inf, np.float32)
         n_hits = n_sent = n_failed = 0
         if fl.k > 0:
             cached = fl.cached
             if fl.miss:
-                if fl.pending is None and fl.replay_ticket:
-                    # (unrouted) replay (DESIGN.md §7): one more pick at
-                    # drain time — a breaker that half-opened while the
-                    # window rode the pipeline serves it (the call IS the
-                    # half-open probe), billed to the replaying backend
-                    fl.replay_ticket = False
-                    fl.backend = self.router.redeem_replay(
-                        self._window_constraint(fl), window=fl.seq)
-                    if fl.backend is not None:
-                        fl.pending = _Resolved(
-                            fl.backend.call(fl.sub_miss, fl.seq))
-                    fl.sub_miss = None
-                if fl.pending is not None:
-                    logits, ok = fl.pending.result()
+                if answer is not None:
+                    logits, ok = answer
                     n_sent = int(ok.sum())
                     n_failed = len(fl.miss) - n_sent
                     bname = fl.backend.name
@@ -1344,119 +1392,122 @@ class CascadeEngine:
         """Fold the finalized window into stats / per-backend billing /
         controller state. Callers MUST commit in submission order — that
         is what keeps streaming accounting bitwise-identical to FIFO."""
-        # per-backend billing/latency attribution (DESIGN.md §6): billed
-        # calls and failures charge the routed backend; cache hits charge
-        # $0 to whichever backend originally filled the entry
-        cost_per = self.cost.backend_cost(fl.backend)
-        lat_per = self.cost.backend_latency(fl.backend)
-        if fl.stage_detail is not None and fl.miss:
-            # per-stage billing split (DESIGN.md §13): each fresh row
-            # charges the hop that answered it at that hop's price; lost
-            # rows charge their failure to the hop whose transport dropped
-            # them. The lump-sum path below stays byte-for-byte for plain
-            # backends and terminal (degenerate 2-tier) stages.
-            sdet = fl.stage_detail
-            split: dict[str, list] = {}
-            for w, j in enumerate(fl.miss):
-                row = split.setdefault(str(sdet["stage"][w]),
-                                       [0, 0, 0.0, 0.0])
-                if fl.cached[j] is not None:
-                    sc, sl = sdet["cost"][w], sdet["latency"][w]
-                    row[0] += 1
-                    row[2] += (self.cost.remote_cost_per_request
-                               if np.isnan(sc) else float(sc))
-                    row[3] += (self.cost.remote_latency_s
-                               if np.isnan(sl) else float(sl))
-                else:
-                    row[1] += 1
-            fl.stage_split = split
-            window_cost = 0.0
-            window_lat = 0.0
-            for name in sorted(split):
-                calls, fails, c, lt = split[name]
-                u = self.stats.backend_usage(name)
-                u.remote_calls += calls
-                u.transport_failures += fails
-                u.cost += c
-                u.remote_latency_s += lt
-                window_cost += c
-                window_lat += lt
-        else:
-            window_cost = fl.n_sent * cost_per
-            window_lat = fl.n_sent * lat_per
-            if fl.n_sent or fl.n_failed:
-                u = self.stats.backend_usage(fl.bname)
-                u.remote_calls += fl.n_sent
-                u.transport_failures += fl.n_failed
-                u.cost += window_cost
-                u.remote_latency_s += window_lat
-        if fl.n_hits and fl.hit_src is not None:
-            miss_set = set(fl.miss)
-            for j in range(fl.k):
-                # policy-forced REJECTED rows are neither misses nor hits
-                if j not in miss_set and j not in fl.forced:
-                    src = fl.hit_src[j]
-                    self.stats.backend_usage(
-                        src if src is not None else UNATTRIBUTED
-                    ).cache_hits += 1
-
-        # per-backend agreement-with-local EMA (DESIGN.md §13): on served
-        # escalated rows, how often the answering backend's argmax agreed
-        # with the local model's — a label-free cross-tier accuracy proxy
-        if fl.k > 0:
-            rb = fl.result["backend"]
-            groups: dict[str, list] = {}
-            for j, i in enumerate(map(int, fl.idx)):
-                if (j not in fl.forced and i < fl.real
-                        and np.isfinite(fl.remote_conf[i])
-                        and rb[i] is not None):
-                    groups.setdefault(str(rb[i]), []).append(
-                        int(fl.pred[i] == fl.local_pred[i]))
-            if groups:
-                fl.agreement = []
-                for name in sorted(groups):
-                    rows = groups[name]
-                    frac = float(np.mean(rows))
+        obs = self.observability
+        with (obs.span("cascade.complete", window=fl.seq)
+              if obs is not None else NULL_SPAN):
+            # per-backend billing/latency attribution (DESIGN.md §6): billed
+            # calls and failures charge the routed backend; cache hits charge
+            # $0 to whichever backend originally filled the entry
+            cost_per = self.cost.backend_cost(fl.backend)
+            lat_per = self.cost.backend_latency(fl.backend)
+            if fl.stage_detail is not None and fl.miss:
+                # per-stage billing split (DESIGN.md §13): each fresh row
+                # charges the hop that answered it at that hop's price; lost
+                # rows charge their failure to the hop whose transport dropped
+                # them. The lump-sum path below stays byte-for-byte for plain
+                # backends and terminal (degenerate 2-tier) stages.
+                sdet = fl.stage_detail
+                split: dict[str, list] = {}
+                for w, j in enumerate(fl.miss):
+                    row = split.setdefault(str(sdet["stage"][w]),
+                                           [0, 0, 0.0, 0.0])
+                    if fl.cached[j] is not None:
+                        sc, sl = sdet["cost"][w], sdet["latency"][w]
+                        row[0] += 1
+                        row[2] += (self.cost.remote_cost_per_request
+                                   if np.isnan(sc) else float(sc))
+                        row[3] += (self.cost.remote_latency_s
+                                   if np.isnan(sl) else float(sl))
+                    else:
+                        row[1] += 1
+                fl.stage_split = split
+                window_cost = 0.0
+                window_lat = 0.0
+                for name in sorted(split):
+                    calls, fails, c, lt = split[name]
                     u = self.stats.backend_usage(name)
-                    u.agreement_rows += len(rows)
-                    u.agreement_ema = (
-                        frac if u.agreement_ema is None
-                        else (1.0 - AGREEMENT_ALPHA) * u.agreement_ema
-                        + AGREEMENT_ALPHA * frac)
-                    fl.agreement.append((name, len(rows), frac,
-                                         u.agreement_ema))
+                    u.remote_calls += calls
+                    u.transport_failures += fails
+                    u.cost += c
+                    u.remote_latency_s += lt
+                    window_cost += c
+                    window_lat += lt
+            else:
+                window_cost = fl.n_sent * cost_per
+                window_lat = fl.n_sent * lat_per
+                if fl.n_sent or fl.n_failed:
+                    u = self.stats.backend_usage(fl.bname)
+                    u.remote_calls += fl.n_sent
+                    u.transport_failures += fl.n_failed
+                    u.cost += window_cost
+                    u.remote_latency_s += window_lat
+            if fl.n_hits and fl.hit_src is not None:
+                miss_set = set(fl.miss)
+                for j in range(fl.k):
+                    # policy-forced REJECTED rows are neither misses nor hits
+                    if j not in miss_set and j not in fl.forced:
+                        src = fl.hit_src[j]
+                        self.stats.backend_usage(
+                            src if src is not None else UNATTRIBUTED
+                        ).cache_hits += 1
 
-        accepted = fl.result["accepted"]
-        # policy-rejected rows never touched a tier past the local model:
-        # they are `rejected`, not `escalations` (the billing invariant
-        # escalations = remote_calls + cache_hits + transport_failures
-        # stays exact — DESIGN.md §8)
-        escalations = fl.k - len(fl.forced)
-        rejected = int((~accepted[:fl.real]).sum())
-        self._account(fl.real, escalations, fl.n_sent, fl.n_hits,
-                      fl.n_failed, rejected,
-                      cost=window_cost,
-                      remote_latency_s=window_lat)
-        wall_s = self._clock() - fl.t0
-        self.stats.record_wall(wall_s, fl.real)
-        if fl.tr is not None:
-            fl.tr["commit"] = self._clock()
-        if self.observability is not None:
-            self._publish_commit(fl, window_cost, escalations, rejected,
-                                 wall_s)
+            # per-backend agreement-with-local EMA (DESIGN.md §13): on served
+            # escalated rows, how often the answering backend's argmax agreed
+            # with the local model's — a label-free cross-tier accuracy proxy
+            if fl.k > 0:
+                rb = fl.result["backend"]
+                groups: dict[str, list] = {}
+                for j, i in enumerate(map(int, fl.idx)):
+                    if (j not in fl.forced and i < fl.real
+                            and np.isfinite(fl.remote_conf[i])
+                            and rb[i] is not None):
+                        groups.setdefault(str(rb[i]), []).append(
+                            int(fl.pred[i] == fl.local_pred[i]))
+                if groups:
+                    fl.agreement = []
+                    for name in sorted(groups):
+                        rows = groups[name]
+                        frac = float(np.mean(rows))
+                        u = self.stats.backend_usage(name)
+                        u.agreement_rows += len(rows)
+                        u.agreement_ema = (
+                            frac if u.agreement_ema is None
+                            else (1.0 - AGREEMENT_ALPHA) * u.agreement_ema
+                            + AGREEMENT_ALPHA * frac)
+                        fl.agreement.append((name, len(rows), frac,
+                                             u.agreement_ema))
+
+            accepted = fl.result["accepted"]
+            # policy-rejected rows never touched a tier past the local model:
+            # they are `rejected`, not `escalations` (the billing invariant
+            # escalations = remote_calls + cache_hits + transport_failures
+            # stays exact — DESIGN.md §8)
+            escalations = fl.k - len(fl.forced)
+            rejected = int((~accepted[:fl.real]).sum())
+            self._account(fl.real, escalations, fl.n_sent, fl.n_hits,
+                          fl.n_failed, rejected,
+                          cost=window_cost,
+                          remote_latency_s=window_lat)
+            wall_s = self._clock() - fl.t0
+            self.stats.record_wall(wall_s, fl.real)
+            if fl.tr is not None:
+                fl.tr["commit"] = self._clock()
+            if self.observability is not None:
+                self._publish_commit(fl, window_cost, escalations, rejected,
+                                     wall_s)
+                if self.controller is not None:
+                    self.controller.event_window = fl.seq
             if self.controller is not None:
-                self.controller.event_window = fl.seq
-        if self.controller is not None:
-            self.controller.observe(fl.conf[:fl.real], escalations, fl.real,
-                                    fl.remote_conf[:fl.real],
-                                    cost=window_cost,
-                                    policy_blocked=fl.blocked)
-        if self.early_emit:
-            # sweep the early-emit triple (the host half may have left it
-            # behind when it raced the device fetch)
-            with self._gate_lock:
-                self._gate_results.pop(fl.seq, None)
-        return fl.result
+                self.controller.observe(fl.conf[:fl.real], escalations, fl.real,
+                                        fl.remote_conf[:fl.real],
+                                        cost=window_cost,
+                                        policy_blocked=fl.blocked)
+            if self.early_emit:
+                # sweep the early-emit triple (the host half may have left it
+                # behind when it raced the device fetch)
+                with self._gate_lock:
+                    self._gate_results.pop(fl.seq, None)
+            return fl.result
 
     def _publish_commit(self, fl: _InFlight, window_cost: float,
                         escalations: int, rejected: int,
@@ -1514,11 +1565,13 @@ class CascadeEngine:
         are accounted but discarded) and shut down every backend's thread
         pool. Half-finalized streaming runs drain too: already-finalized
         windows just commit, the rest finalize first. Idempotent; a no-op
-        on the fused path."""
+        on the fused path, bar the observability facade's gc hook."""
         while self._inflight:
             self.complete_next()
         if self.router is not None:
             self.router.shutdown(wait=wait)
+        if self.observability is not None:
+            self.observability.close()
 
     def __enter__(self) -> "CascadeEngine":
         return self

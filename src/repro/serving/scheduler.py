@@ -73,7 +73,7 @@ from typing import Any, Callable
 import numpy as np
 
 from repro.runtime.observability import (EV_ADMISSION_DEGRADE,
-                                         EV_ADMISSION_SHED)
+                                         EV_ADMISSION_SHED, NULL_SPAN)
 from repro.serving.policy import (BATCHING_MODES, CACHED, LOCAL, REJECTED,
                                   REMOTE, SHED, RequestPolicy, ServeConfig)
 
@@ -152,22 +152,19 @@ class _SlotMap:
     instead of counting whole in-flight windows: a row occupies its slot
     from dispatch until its response is handed back, so a cohort of
     trusted-local rows returns its slots at *gate* time and admission
-    reopens while the window's escalations are still on the wire. The
-    occupancy-fraction EMA is the admission/deadline-feasibility signal
-    (`_queue_wait_estimate`) — the continuous analogue of queue depth in
-    windows."""
+    reopens while the window's escalations are still on the wire.
+    Admission reads ``free``, and the deadline-feasibility estimate
+    (``_queue_wait_estimate``) reads ``occupied``: the continuous analogue
+    of queue depth in windows."""
 
-    __slots__ = ("capacity", "occupied", "peak", "joins", "leaves",
-                 "occupancy_ema", "_alpha")
+    __slots__ = ("capacity", "occupied", "peak", "joins", "leaves")
 
-    def __init__(self, capacity: int, alpha: float = 0.2):
+    def __init__(self, capacity: int):
         self.capacity = max(1, capacity)
         self.occupied = 0
         self.peak = 0
         self.joins = 0
         self.leaves = 0
-        self.occupancy_ema = 0.0
-        self._alpha = alpha
 
     @property
     def free(self) -> int:
@@ -178,16 +175,10 @@ class _SlotMap:
         self.joins += n
         if self.occupied > self.peak:
             self.peak = self.occupied
-        self._observe()
 
     def leave(self, n: int) -> None:
         self.occupied -= n
         self.leaves += n
-        self._observe()
-
-    def _observe(self) -> None:
-        frac = self.occupied / self.capacity
-        self.occupancy_ema += self._alpha * (frac - self.occupancy_ema)
 
 
 class MicrobatchScheduler:
@@ -500,6 +491,19 @@ class MicrobatchScheduler:
         }
         return chunk, batch
 
+    def _begin_cohort(self) -> tuple[list[Request], Any, float]:
+        """Draw the next cohort and dispatch it (``begin_serve``) under a
+        ``scheduler.admit`` span, whose ``queued`` is the rows left in the
+        queue. Returns the cohort, its window handle and the dispatch
+        stamp."""
+        with self._span("scheduler.admit") as span:
+            chunk, batch = self._next_chunk()
+            t_disp = self._clock()
+            fl = self.engine.begin_serve(batch, real_rows=len(chunk),
+                                         **self._serve_args(chunk))
+            span.set_metadata(rows=len(chunk), queued=self._qsize())
+        return chunk, fl, t_disp
+
     @staticmethod
     def _serve_args(chunk: list[Request]) -> dict[str, Any]:
         """policies/t_enq kwargs for the engine (omitted when no row in
@@ -566,53 +570,61 @@ class MicrobatchScheduler:
             "stages": stages,
         })
 
+    def _span(self, name: str):
+        """A profiler span (DESIGN.md §9), or the shared null span while
+        observability is off; attributes go in through ``set_metadata``."""
+        obs = self.engine.observability
+        return obs.span(name) if obs is not None else NULL_SPAN
+
     def _tracing(self) -> bool:
         obs = self.engine.observability
         return obs is not None and obs.trace is not None
 
     def _route(self, chunk: list[Request], res: dict,
                t_disp: float) -> list[Response]:
-        out: list[Response] = []
-        now = self._clock()
-        dispo = res.get("disposition")
-        backend = res.get("backend")
-        cost = res.get("cost")
-        trace = res.get("trace") if self._tracing() else None
-        for i, req in enumerate(chunk):
-            escalated = bool(res["escalated"][i])
-            accepted = bool(res["accepted"][i])
-            if not escalated:
-                src = "local"
-                pred = int(res["local_pred"][i])
-            elif accepted:
-                src = "remote"
-                pred = int(res["prediction"][i])
-            else:
-                src = "fallback"
-                self.fallbacks += 1
-                pred = (self.fallback(req) if self.fallback
-                        else -1)  # "raise Exception" analogue
-            if dispo is not None:
-                d, b, c = dispo[i], backend[i], float(cost[i])
-            else:
-                # fused path: derive attribution from the routing masks
-                d = LOCAL if not escalated else (REMOTE if accepted
-                                                 else REJECTED)
-                b = None
-                c = (self.engine.cost.remote_cost_per_request
-                     if escalated else 0.0)
-            resp = Response(req.uid, pred, src,
-                            float(res["local_conf"][i]),
-                            float(res["remote_conf"][i]),
-                            latency_s=now - req.t_enq,
-                            disposition=d, backend=b, cost=c,
-                            queue_s=t_disp - req.t_enq)
-            self._record(resp, out)
-            if trace is not None:
-                self._emit_span(resp, req, t_disp, trace["stages"],
-                                trace["window"], now,
-                                remote=i in trace["remote_rows"],
-                                hit=i in trace["hit_rows"])
+        with self._span("scheduler.handback") as span:
+            out: list[Response] = []
+            now = self._clock()
+            dispo = res.get("disposition")
+            backend = res.get("backend")
+            cost = res.get("cost")
+            trace = res.get("trace") if self._tracing() else None
+            for i, req in enumerate(chunk):
+                escalated = bool(res["escalated"][i])
+                accepted = bool(res["accepted"][i])
+                if not escalated:
+                    src = "local"
+                    pred = int(res["local_pred"][i])
+                elif accepted:
+                    src = "remote"
+                    pred = int(res["prediction"][i])
+                else:
+                    src = "fallback"
+                    self.fallbacks += 1
+                    pred = (self.fallback(req) if self.fallback
+                            else -1)  # "raise Exception" analogue
+                if dispo is not None:
+                    d, b, c = dispo[i], backend[i], float(cost[i])
+                else:
+                    # fused path: derive attribution from the routing masks
+                    d = LOCAL if not escalated else (REMOTE if accepted
+                                                     else REJECTED)
+                    b = None
+                    c = (self.engine.cost.remote_cost_per_request
+                         if escalated else 0.0)
+                resp = Response(req.uid, pred, src,
+                                float(res["local_conf"][i]),
+                                float(res["remote_conf"][i]),
+                                latency_s=now - req.t_enq,
+                                disposition=d, backend=b, cost=c,
+                                queue_s=t_disp - req.t_enq)
+                self._record(resp, out)
+                if trace is not None:
+                    self._emit_span(resp, req, t_disp, trace["stages"],
+                                    trace["window"], now,
+                                    remote=i in trace["remote_rows"],
+                                    hit=i in trace["hit_rows"])
+            span.set_metadata(rows=len(out))
         return out
 
     def flush(self, pipeline_depth: int | None = None) -> list[Response]:
@@ -633,7 +645,9 @@ class MicrobatchScheduler:
                 return shed + self._flush_pipelined(depth)
         out: list[Response] = shed
         while self._qsize():
-            chunk, batch = self._next_chunk()
+            with self._span("scheduler.admit") as span:
+                chunk, batch = self._next_chunk()
+                span.set_metadata(rows=len(chunk), queued=self._qsize())
             t_disp = self._clock()
             res = self.engine.serve(batch, real_rows=len(chunk),
                                     **self._serve_args(chunk))
@@ -657,10 +671,7 @@ class MicrobatchScheduler:
         pending: deque[tuple[list[Request], float]] = deque()
         while self._qsize() or pending:
             while self._qsize() and len(pending) < depth:
-                chunk, batch = self._next_chunk()
-                t_disp = self._clock()
-                self.engine.begin_serve(batch, real_rows=len(chunk),
-                                        **self._serve_args(chunk))
+                chunk, _fl, t_disp = self._begin_cohort()
                 pending.append((chunk, t_disp))
             # about to block on the oldest window: unpark the double-
             # buffered newest one first, so its remote submission (and in
@@ -701,10 +712,7 @@ class MicrobatchScheduler:
 
         while self._qsize() or windows:
             while self._qsize() and self.engine.inflight < depth:
-                chunk, batch = self._next_chunk()
-                t_disp = self._clock()
-                fl = self.engine.begin_serve(batch, real_rows=len(chunk),
-                                             **self._serve_args(chunk))
+                chunk, fl, t_disp = self._begin_cohort()
                 windows[fl.seq] = _Window(chunk, fl, t_disp)
                 emit_ready_locals()     # previous window's host half ran
                 if not fifo_drain:
@@ -797,10 +805,7 @@ class MicrobatchScheduler:
 
         while self._qsize() or windows:
             while self._qsize() and admissible():
-                chunk, batch = self._next_chunk()
-                t_disp = self._clock()
-                fl = self.engine.begin_serve(batch, real_rows=len(chunk),
-                                             **self._serve_args(chunk))
+                chunk, fl, t_disp = self._begin_cohort()
                 windows[fl.seq] = _Window(chunk, fl, t_disp)
                 slots.join(len(chunk))
                 # run this cohort's GATE half NOW (triple fetch + policy
@@ -834,26 +839,29 @@ class MicrobatchScheduler:
         cache hits (``fl.early``; no remote round trip to wait for — the
         §8 latency fix: their hand-back no longer includes the window
         drain)."""
-        fl = w.fl
-        now = self._clock()
-        tr = fl.tr if self._tracing() else None
-        esc = {int(j) for j in fl.idx} if fl.k else set()
-        for i, req in enumerate(w.chunk):
-            if i in esc or i in w.emitted:
-                continue
-            resp = Response(req.uid, int(fl.local_pred[i]), "local",
-                            float(fl.conf[i]), float("inf"),
-                            latency_s=now - req.t_enq,
-                            disposition=fl.downgraded.get(i, LOCAL),
-                            queue_s=w.t_disp - req.t_enq)
-            self._record(resp, out)
-            if tr is not None:
-                self._emit_span(resp, req, w.t_disp, tr, fl.seq, now,
-                                remote=False, hit=False,
-                                emit_ts=(now if self._slots is not None
-                                         else None))
-            w.emitted.add(i)
-        w.host_emitted = True
+        n0 = len(out)
+        with self._span("scheduler.handback") as span:
+            fl = w.fl
+            now = self._clock()
+            tr = fl.tr if self._tracing() else None
+            esc = {int(j) for j in fl.idx} if fl.k else set()
+            for i, req in enumerate(w.chunk):
+                if i in esc or i in w.emitted:
+                    continue
+                resp = Response(req.uid, int(fl.local_pred[i]), "local",
+                                float(fl.conf[i]), float("inf"),
+                                latency_s=now - req.t_enq,
+                                disposition=fl.downgraded.get(i, LOCAL),
+                                queue_s=w.t_disp - req.t_enq)
+                self._record(resp, out)
+                if tr is not None:
+                    self._emit_span(resp, req, w.t_disp, tr, fl.seq, now,
+                                    remote=False, hit=False,
+                                    emit_ts=(now if self._slots is not None
+                                             else None))
+                w.emitted.add(i)
+            w.host_emitted = True
+            span.set_metadata(rows=len(out) - n0)
         if fl.host_done:
             # window/streaming drains run the whole host half at once, so
             # pre-decided cache hits are known here; the continuous loop
@@ -864,70 +872,76 @@ class MicrobatchScheduler:
     def _emit_early_hits(self, w: _Window, out: list[Response]) -> None:
         """Hand back the window's pre-decided cache hits (``fl.early`` —
         no remote round trip to wait for; the §8 latency fix)."""
-        fl = w.fl
-        now = self._clock()
-        tr = fl.tr if self._tracing() else None
-        for e in fl.early:
-            i = e["row"]
-            if i in w.emitted or i >= len(w.chunk):
-                continue
-            req = w.chunk[i]
-            if e["accepted"]:
-                resp = Response(req.uid, e["prediction"], "remote",
-                                float(fl.conf[i]), e["remote_conf"],
-                                latency_s=now - req.t_enq,
-                                disposition=CACHED, backend=e["backend"],
-                                cost=e["cost"],
-                                queue_s=w.t_disp - req.t_enq)
-            else:
-                self.fallbacks += 1
-                pred = self.fallback(req) if self.fallback else -1
-                resp = Response(req.uid, pred, "fallback",
-                                float(fl.conf[i]), e["remote_conf"],
-                                latency_s=now - req.t_enq,
-                                disposition=REJECTED, backend=e["backend"],
-                                cost=e["cost"],
-                                queue_s=w.t_disp - req.t_enq)
-            self._record(resp, out)
-            if tr is not None:
-                self._emit_span(resp, req, w.t_disp, tr, fl.seq, now,
-                                remote=False, hit=True)
-            w.emitted.add(i)
-        w.early_emitted = True
+        n0 = len(out)
+        with self._span("scheduler.handback") as span:
+            fl = w.fl
+            now = self._clock()
+            tr = fl.tr if self._tracing() else None
+            for e in fl.early:
+                i = e["row"]
+                if i in w.emitted or i >= len(w.chunk):
+                    continue
+                req = w.chunk[i]
+                if e["accepted"]:
+                    resp = Response(req.uid, e["prediction"], "remote",
+                                    float(fl.conf[i]), e["remote_conf"],
+                                    latency_s=now - req.t_enq,
+                                    disposition=CACHED, backend=e["backend"],
+                                    cost=e["cost"],
+                                    queue_s=w.t_disp - req.t_enq)
+                else:
+                    self.fallbacks += 1
+                    pred = self.fallback(req) if self.fallback else -1
+                    resp = Response(req.uid, pred, "fallback",
+                                    float(fl.conf[i]), e["remote_conf"],
+                                    latency_s=now - req.t_enq,
+                                    disposition=REJECTED, backend=e["backend"],
+                                    cost=e["cost"],
+                                    queue_s=w.t_disp - req.t_enq)
+                self._record(resp, out)
+                if tr is not None:
+                    self._emit_span(resp, req, w.t_disp, tr, fl.seq, now,
+                                    remote=False, hit=True)
+                w.emitted.add(i)
+            w.early_emitted = True
+            span.set_metadata(rows=len(out) - n0)
 
     def _emit_escalated(self, w: _Window, res: dict,
                         out: list[Response]) -> None:
         """Hand back the window's escalated rows once finalized."""
-        fl = w.fl
-        now = self._clock()
-        trace = res.get("trace") if self._tracing() else None
-        for j in fl.idx:
-            i = int(j)
-            if i in w.emitted:
-                continue                # handed back at the host half
-            req = w.chunk[i]            # idx only covers genuine rows
-            d, b, c = (res["disposition"][i], res["backend"][i],
-                       float(res["cost"][i]))
-            if bool(res["accepted"][i]):
-                resp = Response(req.uid, int(res["prediction"][i]),
-                                "remote", float(res["local_conf"][i]),
-                                float(res["remote_conf"][i]),
-                                latency_s=now - req.t_enq,
-                                disposition=d, backend=b, cost=c,
-                                queue_s=w.t_disp - req.t_enq)
-            else:
-                self.fallbacks += 1
-                pred = self.fallback(req) if self.fallback else -1
-                resp = Response(req.uid, pred, "fallback",
-                                float(res["local_conf"][i]),
-                                float(res["remote_conf"][i]),
-                                latency_s=now - req.t_enq,
-                                disposition=d, backend=b, cost=c,
-                                queue_s=w.t_disp - req.t_enq)
-            self._record(resp, out)
-            if trace is not None:
-                self._emit_span(resp, req, w.t_disp, trace["stages"],
-                                trace["window"], now,
-                                remote=i in trace["remote_rows"],
-                                hit=i in trace["hit_rows"])
-            w.emitted.add(i)
+        n0 = len(out)
+        with self._span("scheduler.handback") as span:
+            fl = w.fl
+            now = self._clock()
+            trace = res.get("trace") if self._tracing() else None
+            for j in fl.idx:
+                i = int(j)
+                if i in w.emitted:
+                    continue                # handed back at the host half
+                req = w.chunk[i]            # idx only covers genuine rows
+                d, b, c = (res["disposition"][i], res["backend"][i],
+                           float(res["cost"][i]))
+                if bool(res["accepted"][i]):
+                    resp = Response(req.uid, int(res["prediction"][i]),
+                                    "remote", float(res["local_conf"][i]),
+                                    float(res["remote_conf"][i]),
+                                    latency_s=now - req.t_enq,
+                                    disposition=d, backend=b, cost=c,
+                                    queue_s=w.t_disp - req.t_enq)
+                else:
+                    self.fallbacks += 1
+                    pred = self.fallback(req) if self.fallback else -1
+                    resp = Response(req.uid, pred, "fallback",
+                                    float(res["local_conf"][i]),
+                                    float(res["remote_conf"][i]),
+                                    latency_s=now - req.t_enq,
+                                    disposition=d, backend=b, cost=c,
+                                    queue_s=w.t_disp - req.t_enq)
+                self._record(resp, out)
+                if trace is not None:
+                    self._emit_span(resp, req, w.t_disp, trace["stages"],
+                                    trace["window"], now,
+                                    remote=i in trace["remote_rows"],
+                                    hit=i in trace["hit_rows"])
+                w.emitted.add(i)
+            span.set_metadata(rows=len(out) - n0)

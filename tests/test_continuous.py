@@ -398,10 +398,13 @@ def test_slot_map_telemetry_ema():
     sm = _SlotMap(32)
     assert sm.free == 32
     sm.join(16)
-    assert sm.free == 16 and sm.peak == 16
-    assert 0.0 < sm.occupancy_ema <= 0.5
+    assert sm.free == 16 and sm.peak == 16 and sm.occupied == 16
+    sm.join(8)
     sm.leave(16)
-    assert sm.occupied == 0 and sm.leaves == 16
+    # the peak holds the high-water mark after rows leave
+    assert sm.occupied == 8 and sm.peak == 24 and sm.free == 24
+    sm.leave(8)
+    assert sm.occupied == 0 and sm.leaves == 24 and sm.joins == 24
 
 
 # --------------------------------------------- /metrics scrape endpoint

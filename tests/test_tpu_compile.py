@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 SERVE_BATCH = 32
 
@@ -107,6 +111,35 @@ def test_gated_local_step_holds_pallas_gate(one_chip, on_tpu):
         jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)))
     assert hlo.count("tpu_custom_call") >= 2        # scoring + selection
+
+
+def test_gated_step_keeps_the_names_the_benchmark_matches(one_chip, on_tpu):
+    """``chipbench/steps.py`` finds the gated step's program by
+    ``STEP_MODULES`` and the fused head gate kernel by the substring
+    ``HEAD_GATE_KERNEL`` of its op name: only the scoring kernel carries
+    it, not the selection kernel beside it."""
+    from chipbench.steps import HEAD_GATE_KERNEL, STEP_MODULES
+    from repro.kernels.fused_head_gate.ops import FusedLocalHead
+    from repro.serving.engine import make_gated_local_step
+    d, c = 128, 512
+    rng = np.random.default_rng(0)
+    emb = jnp.asarray(rng.normal(size=(64, d)), jnp.bfloat16)
+    head = FusedLocalHead(trunk=lambda tk: emb[tk].mean(1),
+                          w=jnp.asarray(rng.normal(size=(d, c)),
+                                        jnp.bfloat16))
+    step = jax.jit(make_gated_local_step(head, emit=lambda *a: None))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    hlo = _hlo(step.lower(
+        jax.ShapeDtypeStruct((SERVE_BATCH, 16), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
+        scalar, scalar))
+    assert re.match(r"HloModule (\w+),", hlo).group(1) in STEP_MODULES
+    kernels = re.findall(r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                         hlo, re.M)
+    assert len(kernels) == 2, kernels                # scoring + selection
+    assert [k for k in kernels if HEAD_GATE_KERNEL in k] == [
+        k for k in kernels if k.startswith("head_gate_scores_pallas")]
+    assert sum(HEAD_GATE_KERNEL in k for k in kernels) == 1
 
 
 def test_gated_local_step_callable_supervisor_takes_jnp(one_chip, on_tpu):
