@@ -141,7 +141,8 @@ SPAN_STAGES = ("enqueue", "pack", "join", "dispatch", "gate", "route",
 # was doing in it
 SPAN_NAMES = ("scheduler.admit", "cascade.gate", "cascade.route",
               "cascade.remote_wait", "cascade.complete", "scheduler.handback",
-              "cascade.early_emit", "transport.call", "python.gc")
+              "cascade.early_emit", "transport.call", "python.gc",
+              "cascade.gate_wait")
 
 # fixed histogram buckets for latency-shaped observations (seconds);
 # +inf is implicit (the _count line covers it)

@@ -120,6 +120,11 @@ UNATTRIBUTED = "(cache)"
 # EMA weight for the per-backend agreement-with-local signal
 # (DESIGN.md §13): one observation per committed window per backend
 AGREEMENT_ALPHA = 0.2
+# how often ``wait_gate`` looks at the step's output when no early-emit
+# callback will wake it; with early emit armed the callback does, and
+# the longer timeout is only a safety net
+GATE_POLL_S = 0.002
+GATE_EMIT_POLL_S = 0.02
 
 
 @dataclass(frozen=True)
@@ -359,6 +364,17 @@ def make_gated_local_step(local_apply: Callable, supervisor="max_softmax",
     return step
 
 
+def _host_cpu():
+    """The host's CPU device where the default device is an accelerator;
+    None where the default is the CPU or no CPU backend was started."""
+    if jax.default_backend() == "cpu":
+        return None
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
 def _leading_rows(tree: Any) -> int:
     if isinstance(tree, dict):
         return _leading_rows(next(iter(tree.values())))
@@ -549,6 +565,11 @@ class CascadeEngine:
         # escalation count never triggers a compile
         self._remote_scorer = jax.jit(
             lambda lg: (sup(lg).astype(jnp.float32), jnp.argmax(lg, -1)))
+        # ... on the host's CPU device: the logits come off the wire and
+        # the scores go back to the host, and a program on the chip would
+        # queue behind the local step in flight, holding an earlier
+        # window's hand-back for the whole step (DESIGN.md §11)
+        self._score_device = _host_cpu()
         # observability facade (DESIGN.md §9): None = disabled; install()
         # wires the router/transports/controller into the shared event
         # log and registers the snapshot-time metrics collector
@@ -773,6 +794,52 @@ class CascadeEngine:
         if self._inflight and not self._inflight[-1].host_done:
             self._host_begin(self._inflight[-1])
 
+    def gate_ready(self) -> bool:
+        """Whether ``flush_gate`` would run without waiting on the device:
+        the newest window's gate half ran, its triple was early-emitted,
+        or its step output is ready. Looks only; computes nothing."""
+        if not self._inflight:
+            return True
+        fl = self._inflight[-1]
+        if fl.gate_done or (self.early_emit
+                            and self.gate_result(fl.seq) is not None):
+            return True
+        return all(a.is_ready() for a in jax.tree.leaves(fl.gate_dev))
+
+    def wait_gate(self) -> bool:
+        """Block until an earlier window's remote answer has landed
+        (False) or the newest window's gate is ready (True), under a
+        ``cascade.gate_wait`` span. Both set the ready event: the remote
+        futures and the early-emit callback. Without early emit the step's
+        output is looked at every ``GATE_POLL_S``. Answers go first where
+        both are in: they landed while the step ran. The continuous loop
+        waits here while earlier escalations are on the wire, so their
+        answers hand back during the step (DESIGN.md §11)."""
+        poll = GATE_EMIT_POLL_S if self.early_emit else GATE_POLL_S
+        obs = self.observability
+        with (obs.span("cascade.gate_wait", window=self._seq,
+                       inflight=len(self._inflight))
+              if obs is not None else NULL_SPAN):
+            while True:
+                self._ready.clear()
+                if any(fl.pending is not None and not fl.finalized
+                       and fl.pending.done() for fl in self._inflight):
+                    return False
+                if self.gate_ready():
+                    return True
+                self._ready.wait(poll)
+
+    def wake(self) -> None:
+        """Wake a blocked ``complete_ready`` to look at its ``until``."""
+        self._ready.set()
+
+    def complete_landed(self) -> list[tuple[int, dict[str, np.ndarray]]]:
+        """``complete_ready()`` for a caller waiting on the newest window's
+        gate, without a controller: finalize and commit the windows whose
+        remote answers have landed, but never run a parked window's host
+        half, whose gate fetch would wait out the step in flight."""
+        return self._scan_ready(unpark=False)
+
     def complete_next(self) -> dict[str, np.ndarray] | None:
         """Drain the OLDEST in-flight window (blocks until its remote
         responses land). FIFO draining keeps accounting and controller
@@ -785,7 +852,8 @@ class CascadeEngine:
         return self._commit(fl)
 
     # -- streaming completion (DESIGN.md §7) ---------------------------
-    def complete_ready(self, block: bool = False
+    def complete_ready(self, block: bool = False,
+                       until: Callable[[], bool] | None = None
                        ) -> list[tuple[int, dict[str, np.ndarray]]]:
         """Per-request streaming drain: finalize every in-flight window
         whose remote responses have landed and hand back their results —
@@ -816,14 +884,15 @@ class CascadeEngine:
         Returns ``(seq, result)`` pairs for windows finalized by THIS
         call, ``seq`` being the value on the ``begin_serve`` handle. With
         ``block=True`` waits until at least one window finalizes
-        (returns ``[]`` immediately when nothing is in flight)."""
+        (returns ``[]`` immediately when nothing is in flight), or until
+        ``until()`` holds, looked at whenever the wait wakes (``wake``)."""
         while True:
             events = self._scan_ready()
             if events or not block or not self._inflight:
                 return events
             self._ready.clear()
             events = self._scan_ready()  # racing resolve before clear()
-            if events:
+            if events or (until is not None and until()):
                 return events
             # event wakeup from any backend's pool; the timeout is a
             # safety net, not a poll interval
@@ -839,9 +908,12 @@ class CascadeEngine:
         while self._inflight:
             yield from self.complete_ready(block=True)
 
-    def _scan_ready(self) -> list[tuple[int, dict[str, np.ndarray]]]:
+    def _scan_ready(self, unpark: bool = True
+                    ) -> list[tuple[int, dict[str, np.ndarray]]]:
         """One non-blocking pass of the streaming drain: finalize every
         ready window, then commit the contiguous finalized prefix.
+        ``unpark`` runs the host half of a parked window left alone
+        (static thresholds; the controller path always does).
 
         With a controller, finalize NEVER runs ahead of commit: window
         i+1's acceptance must see the t_remote that window i's
@@ -866,7 +938,8 @@ class CascadeEngine:
             progressed = False
             # a lone parked window cannot be unblocked by anything else:
             # run its host half so its remote round trip starts
-            if len(self._inflight) == 1 and not self._inflight[0].host_done:
+            if (unpark and len(self._inflight) == 1
+                    and not self._inflight[0].host_done):
                 self._host_begin(self._inflight[0])
                 progressed = True
             head = self._inflight[0]
@@ -1216,6 +1289,8 @@ class CascadeEngine:
         lg = np.zeros((max(self.batch_size, n),) + np.shape(rows[0]),
                       np.float32)
         lg[:n] = rows
+        if self._score_device is not None:
+            lg = jax.device_put(lg, self._score_device)
         conf, pred = jax.device_get(self._remote_scorer(lg))
         return conf[:n], pred[:n]
 

@@ -126,6 +126,9 @@ class AdmissionStats:
     shed: int = 0               # refused, answered via fallback (SHED)
     shed_reasons: dict = field(default_factory=dict)     # reason -> n
     degrade_reasons: dict = field(default_factory=dict)  # reason -> n
+    # continuous batching (§11): cohorts admitted while earlier
+    # cohorts' escalations were on the wire
+    on_wire: int = 0
 
 
 class _Window:
@@ -151,11 +154,16 @@ class _SlotMap:
     of a persistent padded batch (``batch_size × pipeline_depth`` rows)
     instead of counting whole in-flight windows: a row occupies its slot
     from dispatch until its response is handed back, so a cohort of
-    trusted-local rows returns its slots at *gate* time and admission
-    reopens while the window's escalations are still on the wire.
-    Admission reads ``free``, and the deadline-feasibility estimate
-    (``_queue_wait_estimate``) reads ``occupied``: the continuous analogue
-    of queue depth in windows."""
+    trusted-local rows returns its slots at *gate* time. Rows that wait
+    only on the wire do not block a FULL cohort: when every occupied
+    slot holds such a row, a full queued cohort is admitted even past
+    the capacity (so ``occupied`` and ``peak`` may exceed it), while a
+    partial cohort still waits for free slots. Each dispatch escalates
+    ``ceil(ρ·batch_size)`` rows or all of a smaller cohort, so a cohort
+    sent short would raise the remote bill; a full one sent early does
+    the same work sooner. Admission reads ``free``, and the
+    deadline-feasibility estimate (``_queue_wait_estimate``) reads
+    ``occupied``: the continuous analogue of queue depth in windows."""
 
     __slots__ = ("capacity", "occupied", "peak", "joins", "leaves")
 
@@ -307,6 +315,11 @@ class MicrobatchScheduler:
              else self.queue).append(req)
         else:
             self.queue.append(req)
+        if (self.batching == "continuous"
+                and self._next_cohort_rows() >= self.engine.batch_size):
+            # a full cohort may ride the wire: the serve loop's remote
+            # wait looks at admission again (DESIGN.md §11)
+            self.engine.wake()
         return None
 
     # -- overload admission control (DESIGN.md §10) ---------------------
@@ -468,6 +481,10 @@ class MicrobatchScheduler:
     def _qsize(self) -> int:
         return len(self.queue) + len(self.cold)
 
+    def _next_cohort_rows(self) -> int:
+        """Rows the next ``_next_chunk`` could draw (its queue's length)."""
+        return len(self.cold) if self.cold else len(self.queue)
+
     def _pad(self, reqs: list[Request]) -> list[Request]:
         b = self.engine.batch_size
         return reqs + [reqs[-1]] * (b - len(reqs))
@@ -491,17 +508,22 @@ class MicrobatchScheduler:
         }
         return chunk, batch
 
-    def _begin_cohort(self) -> tuple[list[Request], Any, float]:
+    def _begin_cohort(self, on_wire: int | None = None
+                      ) -> tuple[list[Request], Any, float]:
         """Draw the next cohort and dispatch it (``begin_serve``) under a
         ``scheduler.admit`` span, whose ``queued`` is the rows left in the
-        queue. Returns the cohort, its window handle and the dispatch
-        stamp."""
+        queue and ``on_wire``, where given, the rows of earlier cohorts
+        awaiting the remote. Returns the cohort, its window handle and the
+        dispatch stamp."""
         with self._span("scheduler.admit") as span:
             chunk, batch = self._next_chunk()
             t_disp = self._clock()
             fl = self.engine.begin_serve(batch, real_rows=len(chunk),
                                          **self._serve_args(chunk))
-            span.set_metadata(rows=len(chunk), queued=self._qsize())
+            meta = {"rows": len(chunk), "queued": self._qsize()}
+            if on_wire is not None:
+                meta["on_wire"] = on_wire
+            span.set_metadata(**meta)
         return chunk, fl, t_disp
 
     @staticmethod
@@ -749,10 +771,21 @@ class MicrobatchScheduler:
           before the next cohort is even formed;
         * without a live controller, admission is keyed on FREE SLOTS
           rather than in-flight window count: a cohort of trusted locals
-          returns its slots at gate time and the loop admits the next
-          cohort while earlier escalations are still on the wire (the
-          row-level backpressure bound is the slot capacity, not
-          ``depth`` windows).
+          returns its slots at gate time (the row-level backpressure
+          bound is the slot capacity, not ``depth`` windows). Rows that
+          wait only on the wire do not block a FULL cohort: with every
+          occupied slot held by such rows and ``batch_size`` rows queued,
+          the next cohort is admitted at once, up to one window awaiting
+          the remote per transport pool thread (a call beyond the pool's
+          width only queues); ``submit`` wakes the loop's remote wait
+          when such a cohort fills meanwhile. A partial cohort still
+          waits for free slots, so cohort sizes, and with them the
+          per-dispatch escalation count ``min(ceil(ρ·batch_size),
+          rows)``, stay what they would be. While such a cohort's step runs the loop waits
+          on the engine's ready event (``wait_gate``), which wakes on
+          whichever lands first: an earlier cohort's remote answers,
+          handed back at once (``complete_landed``), or the cohort's own
+          gate triple.
 
         Cohorts are still drawn cold-first exactly like ``_next_chunk``
         (hot/cold are slot-priority classes; the never-mixed invariant is
@@ -771,7 +804,9 @@ class MicrobatchScheduler:
         windows: dict[int, _Window] = {}        # seq -> bookkeeping
         fifo_drain = self.engine.controller is not None
         slots = self._slots
-        slots.capacity = max(1, self.engine.batch_size * depth)
+        b = self.engine.batch_size
+        slots.capacity = max(1, b * depth)
+        wire_cap = min(be.config.max_concurrent for be in self.engine.router)
 
         def sync_slots(w: _Window) -> None:
             freed = len(w.emitted) - w.left
@@ -798,16 +833,33 @@ class MicrobatchScheduler:
             self._emit_escalated(w, res, out)
             sync_slots(w)
 
+        def on_wire_admissible() -> bool:
+            # rows that wait only on the wire do not block a full cohort
+            return (self._next_cohort_rows() >= b and len(windows) < wire_cap
+                    and all(w.fl.pending is not None
+                            for w in windows.values()))
+
         def admissible() -> bool:
             if fifo_drain:
                 return self.engine.inflight < depth
-            return slots.free >= self.engine.batch_size
+            return slots.free >= b or on_wire_admissible()
+
+        def await_gate() -> None:
+            # earlier escalations on the wire: hand their answers back as
+            # they land instead of blocking in the gate's device fetch
+            while len(windows) > 1 and not self.engine.wait_gate():
+                for seq, res in self.engine.complete_landed():
+                    emit_window(seq, res)
 
         while self._qsize() or windows:
             while self._qsize() and admissible():
-                chunk, fl, t_disp = self._begin_cohort()
+                wire = slots.occupied
+                chunk, fl, t_disp = self._begin_cohort(on_wire=wire)
+                self.admission.on_wire += wire > 0
                 windows[fl.seq] = _Window(chunk, fl, t_disp)
                 slots.join(len(chunk))
+                if not fifo_drain:
+                    await_gate()
                 # run this cohort's GATE half NOW (triple fetch + policy
                 # pass only — the early-emitted triple is already on the
                 # host) and hand its trusted locals back before the
@@ -828,7 +880,10 @@ class MicrobatchScheduler:
                 res = self.engine.complete_next()
                 emit_window(min(windows), res)      # FIFO = lowest seq
             else:
-                for seq, res in self.engine.complete_ready(block=True):
+                # the remote wait also ends when a full cohort queued
+                # meanwhile may ride the wire (``submit`` wakes it)
+                for seq, res in self.engine.complete_ready(
+                        block=True, until=on_wire_admissible):
                     emit_window(seq, res)
         return out
 

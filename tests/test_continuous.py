@@ -371,6 +371,190 @@ def test_trusted_locals_hand_back_while_escalation_stuck():
     engine.close()
 
 
+# --------------------------- full cohorts admitted while on the wire
+
+def flush_within(sched, limit_s):
+    """``sched.flush()`` with a time limit: a loop that hangs fails this
+    test instead of wedging the suite."""
+    out, errors = [], []
+
+    def run():
+        try:
+            out.extend(sched.flush())
+        except BaseException as e:          # re-raised in the test
+            errors.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(limit_s)
+    assert not t.is_alive(), f"flush still running after {limit_s} s"
+    if errors:
+        raise errors[0]
+    return out
+
+
+def record_dispatches(engine):
+    """Wrap ``engine.begin_serve``: the list fills with (time the
+    dispatch began, window handle) per cohort."""
+    log = []
+    begin = engine.begin_serve
+
+    def wrapped(*args, **kw):
+        t = time.perf_counter()
+        fl = begin(*args, **kw)
+        log.append((t, fl))
+        return fl
+
+    engine.begin_serve = wrapped
+    return log
+
+
+def sleepy(remote_s, returns):
+    """A remote that holds each call ``remote_s`` and notes its return."""
+    def remote(x):
+        time.sleep(remote_s)
+        returns.append(time.perf_counter())
+        return remote_apply(x)
+    return remote
+
+
+def submit_all(sched, xs):
+    for i, row in enumerate(xs):
+        sched.submit(Request(uid=i, local_input=row, remote_input=row))
+
+
+def test_full_cohort_admitted_while_escalations_on_the_wire():
+    """At depth 1 the first cohort's escalations hold slots until their
+    answers land, yet with full cohorts queued the next one is dispatched
+    at once: rows that wait only on the wire do not block it."""
+    rng = np.random.default_rng(11)
+    xs, _ = make_stream(rng, 24)
+    returns = []
+    sched, engine = build(sleepy(0.3, returns), batch=8, depth=1)
+    engine.warmup(xs[:8], remote_classes=xs.shape[1])
+    log = record_dispatches(engine)
+    submit_all(sched, xs)
+    out = flush_within(sched, 60)
+    assert sorted(r.uid for r in out) == list(range(24))
+    assert len(log) == 3
+    assert log[1][0] < min(returns)     # before the first call returned
+    assert sched.admission.on_wire >= 1
+    assert sched._slots.occupied == 0
+    engine.close()
+
+
+def test_full_cohort_arriving_during_the_remote_wait_is_admitted():
+    """The loop's remote wait also ends when a full cohort is queued
+    meanwhile: rows submitted from another thread while the first
+    cohort's call is out go out before that call returns."""
+    rng = np.random.default_rng(14)
+    xs, _ = make_stream(rng, 16)
+    returns, calling = [], threading.Event()
+    slow = sleepy(0.5, returns)
+
+    def remote(x):
+        calling.set()
+        return slow(x)
+
+    sched, engine = build(remote, batch=8, depth=1)
+    engine.warmup(xs[:8], remote_classes=xs.shape[1])
+    log = record_dispatches(engine)
+    submit_all(sched, xs[:8])
+    out, errors = [], []
+
+    def run():
+        try:
+            out.extend(sched.flush())
+        except BaseException as e:          # re-raised in the test
+            errors.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    assert calling.wait(30)
+    for i, row in enumerate(xs[8:], start=8):
+        sched.submit(Request(uid=i, local_input=row, remote_input=row))
+    t.join(60)
+    assert not t.is_alive(), "flush still running after 60 s"
+    if errors:
+        raise errors[0]
+    assert sorted(r.uid for r in out) == list(range(16))
+    assert len(log) == 2
+    assert log[1][0] < min(returns)     # before the first call returned
+    assert sched.admission.on_wire == 1
+    engine.close()
+
+
+def test_partial_cohort_waits_for_the_wire_and_counts_match_window():
+    """A cohort short of ``batch_size`` is never admitted early: it waits
+    until the wire rows have left, so every dispatch holds the rows and
+    escalates the count that the window drain gives it."""
+    rng = np.random.default_rng(12)
+    xs, _ = make_stream(rng, 8 + 8 + 5)
+
+    def run(batching):
+        returns = []
+        sched, engine = build(sleepy(0.2, returns), batch=8, depth=1,
+                              batching=batching)
+        engine.warmup(xs[:8], remote_classes=xs.shape[1])
+        log = record_dispatches(engine)
+        submit_all(sched, xs)
+        out = flush_within(sched, 60)
+        engine.close()
+        return out, log, returns, sched, engine
+
+    r_win, log_win, _, _, e_win = run("window")
+    r_con, log_con, returns, sched, e_con = run("continuous")
+    counts = [(fl.real, fl.k) for _, fl in log_con]
+    assert counts == [(fl.real, fl.k) for _, fl in log_win]
+    assert counts == [(8, 4), (8, 4), (5, 4)]
+    # the second (full) cohort went out on the wire, the third (partial)
+    # only after both calls before it had returned
+    assert log_con[1][0] < min(returns)
+    assert log_con[2][0] > sorted(returns)[1]
+    assert sched.admission.on_wire == 1
+    assert by_uid(r_win) == by_uid(r_con)
+    assert_same_accounting(e_win, e_con)
+
+
+def test_escalations_hand_back_before_the_next_gate_when_step_is_slower():
+    """With a local step slower than the remote, the answers of a cohort
+    on the wire land while the next cohort's step runs; the loop hands
+    them back before that cohort's gate clears, not after."""
+    def slow_local(x):
+        def nap(v):
+            time.sleep(0.3)
+            return v
+        x = jax.pure_callback(nap, jax.ShapeDtypeStruct(x.shape, x.dtype),
+                              x)
+        return local_apply(x)
+
+    rng = np.random.default_rng(13)
+    xs, _ = make_stream(rng, 24)
+    returns = []
+    engine = CascadeEngine(slow_local, batch_size=8,
+                           remote_fraction_budget=0.5, t_remote=0.0,
+                           transport=RemoteTransport(sleepy(0.05, returns),
+                                                     quiet_tconf()))
+    sched = MicrobatchScheduler(engine, fallback=lambda r: -7,
+                                pipeline_depth=1,
+                                completion_mode="streaming",
+                                batching="continuous")
+    engine.warmup(xs[:8], remote_classes=xs.shape[1])
+    log = record_dispatches(engine)
+    submit_all(sched, xs)
+    out = flush_within(sched, 60)
+    assert sorted(r.uid for r in out) == list(range(24))
+    assert log[1][0] < min(returns)     # cohort 2 rode cohort 1's call
+    order = {r.uid: i for i, r in enumerate(out)}   # hand-back order
+    first_escalated = [order[r.uid] for r in out
+                       if r.uid < 8 and r.source != "local"]
+    second_local = [order[r.uid] for r in out
+                    if 8 <= r.uid < 16 and r.source == "local"]
+    assert first_escalated and second_local
+    assert max(first_escalated) < min(second_local)
+    engine.close()
+
+
 def test_queue_wait_estimate_prices_slot_occupancy():
     """Continuous mode prices admission against slot occupancy amortized
     over the pipeline width; window mode prices whole windows ahead."""

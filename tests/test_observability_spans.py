@@ -1,7 +1,8 @@
 """Profiler spans of the serve loop (DESIGN.md §9 span table): a traced
 continuous-batching flush puts every span on the profiler's trace, where
-the benchmark's reduction (``chipbench/trace.py``) finds them; the remote
-wait covers the remote round trips the serving thread sat out; with
+the benchmark's reduction (``chipbench/trace.py``) finds them; full
+cohorts are admitted while earlier calls are on the wire, and the remote
+wait covers the round trips the serving thread sat out after; with
 observability off no ``TraceAnnotation`` is ever built; the gated step
 keeps the program names the benchmark's device-trace readers match."""
 
@@ -12,6 +13,7 @@ import re
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
+from chipbench import spec  # noqa: E402
 from chipbench import trace as T  # noqa: E402
 from chipbench.steps import STEP_MODULES  # noqa: E402
 from repro.runtime import RemoteTransport, TransportConfig  # noqa: E402
@@ -101,17 +104,24 @@ def test_traced_flush_puts_every_span_on_the_profiler_trace(tmp_path):
     calls = by["transport.call"]
     assert len(calls) == 4
     assert all(c.end - c.start >= REMOTE_S for c in calls)
-    # while the queue still held rows (before the last cohort's admit),
-    # the serving thread waited out nearly all of each remote round trip
-    last_admit = max(e.start for e in by["scheduler.admit"])
-    queued = [c for c in calls if c.end <= last_admit]
-    assert len(queued) == 3
-    covered = overlap(by["cascade.remote_wait"], queued)
-    assert covered >= 0.8 * T.seconds(queued), (covered, T.seconds(queued))
-    # leaves: no remote wait sits inside a span that does work
+    # the queue held full cohorts, so each one after the first was
+    # admitted while the calls before it were on the wire
+    assert sched.admission.on_wire == 3
+    share = spec.reader("admit_on_wire_share")(SimpleNamespace(trace=tr))
+    assert share == pytest.approx(75.0)
+    # with nothing left to admit, the serving thread spent nearly all of
+    # the rest of the round trips waiting on them, or handing back those
+    # that had landed while the others were still out
+    last_route = max(e.end for e in by["cascade.route"])
+    rest = T.clip(calls, last_route, tr.window[1])
+    covered = overlap(by["cascade.remote_wait"] + by["cascade.complete"]
+                      + by["scheduler.handback"], rest)
+    assert covered >= 0.8 * T.busy_seconds(rest, *tr.window), covered
+    # leaves: no wait sits inside a span that does work
     for work in ("scheduler.admit", "cascade.gate", "cascade.route",
                  "cascade.complete", "scheduler.handback"):
-        assert overlap(by[work], by["cascade.remote_wait"]) == 0.0, work
+        for wait in ("cascade.remote_wait", "cascade.gate_wait"):
+            assert overlap(by[work], by[wait]) == 0.0, (work, wait)
 
     hook = obs._gc_spans
     assert hook in gc.callbacks
