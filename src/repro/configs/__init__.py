@@ -9,7 +9,7 @@ from __future__ import annotations
 import importlib
 
 from repro.configs.base import (INPUT_SHAPES, ModelConfig, ShapeConfig,
-                                shape_applicable)
+                                YarnRope, shape_applicable)
 
 ARCH_MODULES = {
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
@@ -37,4 +37,4 @@ def list_archs() -> list[str]:
 
 
 __all__ = ["get_config", "list_archs", "ModelConfig", "ShapeConfig",
-           "INPUT_SHAPES", "shape_applicable", "ARCH_MODULES"]
+           "YarnRope", "INPUT_SHAPES", "shape_applicable", "ARCH_MODULES"]

@@ -12,6 +12,18 @@ from dataclasses import dataclass
 
 
 @dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (``rope_scaling`` with ``type: yarn`` in its ``config.json``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | vlm | audio
@@ -27,6 +39,7 @@ class ModelConfig:
     attn_bias: bool = False            # qwen2-style QKV bias
     sliding_window: int = 0            # 0 = full attention; >0 = SWA window
     rope_theta: float = 10_000.0
+    rope_scaling: YarnRope | None = None   # None = plain rotary positions
 
     # ---- MLA (DeepSeek-V2 multi-head latent attention) ----
     use_mla: bool = False
@@ -41,6 +54,12 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0                  # per-expert hidden dim
     first_dense_layers: int = 0        # leading layers that use a dense MLP
+    norm_topk_prob: bool = True        # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
+    # the experts this device holds: [first_expert, first_expert +
+    # experts_held) of num_experts (0 = all); the router still scores all
+    experts_held: int = 0
+    first_expert: int = 0
     router_aux_loss_coef: float = 0.001
     capacity_factor: float = 1.25
 
@@ -77,6 +96,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this device holds."""
+        return self.first_expert, self.experts_held or self.num_experts
 
     @property
     def supports_decode(self) -> bool:
@@ -121,6 +145,8 @@ class ModelConfig:
                 num_shared_experts=min(1, self.num_shared_experts),
                 moe_d_ff=128,
                 first_dense_layers=min(1, self.first_dense_layers),
+                experts_held=0,
+                first_expert=0,
             )
         if self.use_mla:
             changes.update(kv_lora_rank=64, qk_nope_head_dim=32,

@@ -13,6 +13,9 @@ selection.
 ``trunk`` (inputs -> hidden [B, D]) plus the final projection ``(w
 [D, C], bias [C])``. ``CascadeEngine`` accepts it anywhere a plain
 ``local_apply`` is accepted and routes the gate through this fused op.
+A ``counted`` trunk also returns device counters (``{name: array}``, e.g.
+an expert layer's routed rows), which ride to the host with the gate's
+outputs.
 
 Selection and the ``gather`` hook are the standalone gate's (see
 confidence_gate.ops); one ``use_pallas`` decision steers both passes.
@@ -47,9 +50,15 @@ class FusedLocalHead:
     trunk: Callable[[jnp.ndarray], jnp.ndarray]
     w: jnp.ndarray                                         # [D, C]
     bias: jnp.ndarray | None = None                        # [C]
+    counted: bool = False       # trunk returns (hidden, {name: counter})
+
+    def split(self, local_batch) -> tuple[jnp.ndarray, dict | None]:
+        """(hidden [B, D], the trunk's counters or None)."""
+        out = self.trunk(local_batch)
+        return out if self.counted else (out, None)
 
     def __call__(self, local_batch) -> jnp.ndarray:
-        return head_logits_ref(self.trunk(local_batch), self.w, self.bias)
+        return head_logits_ref(self.split(local_batch)[0], self.w, self.bias)
 
 
 def head_gate_scores(hidden: jnp.ndarray, w: jnp.ndarray,

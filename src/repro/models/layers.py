@@ -8,11 +8,13 @@ math, validated against these implementations.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnRope
 from repro.models.scan_config import scan_unroll
 
 Params = dict
@@ -86,14 +88,58 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor for a context ``scale``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, y: YarnRope) -> np.ndarray:
+    """YaRN inverse frequencies [hd/2] (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): dimensions that turn fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, those that turn more than ``beta_fast`` times keep their
+    frequency, and a linear ramp joins the two."""
+    def turns_dim(turns):
+        return (head_dim * math.log(y.original_max_position_embeddings
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(turns_dim(y.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(y.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return (extra / y.factor * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def yarn_softmax_scale(q_head_dim: int, y: YarnRope | None) -> float:
+    """``q_head_dim^-0.5``, times ``mscale(factor, mscale_all_dim)^2``
+    under YaRN."""
+    scale = q_head_dim ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: [..., T, H, hd]; positions: broadcastable to [..., T]."""
+               theta: float, scaling: YarnRope | None = None) -> jnp.ndarray:
+    """x: [..., T, H, hd]; positions: broadcastable to [..., T]. Rotate-half
+    layout; ``scaling`` applies YaRN's frequencies and cos/sin scale."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # [hd/2]
+    if scaling is None:
+        freqs = rope_freqs(hd, theta)                   # [hd/2]
+    else:
+        freqs = jnp.asarray(yarn_freqs(hd, theta, scaling))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, hd/2]
     angles = angles[..., None, :]                       # [..., T, 1, hd/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

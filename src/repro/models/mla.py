@@ -7,17 +7,22 @@ key: cache bytes per token = kv_lora_rank + qk_rope_head_dim, a ~14x
 reduction vs. vanilla GQA for deepseek-v2-lite. This mirrors DeepSeek-V2's
 serving optimisation and is the arch where the paper's "expensive remote
 model" tier benefits most from cache compression.
+
+Rotary positions use the rotate-half layout (the published checkpoint's
+interleaved rope columns map onto it by a fixed permutation) and, where
+the config has ``rope_scaling``, YaRN's frequencies, with the softmax scale
+``q_head_dim^-0.5 * mscale^2``.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.scan_config import scan_unroll
-from repro.models.layers import Params, apply_rope, dense, dense_params, rms_norm
+from repro.models.layers import (Params, apply_rope, dense, dense_params,
+                                 rms_norm, yarn_softmax_scale)
 
 
 def mla_params(key, cfg: ModelConfig, dtype) -> Params:
@@ -49,7 +54,7 @@ def _latents(cfg: ModelConfig, p: Params, x, positions):
     """Returns (c_kv [B,T,r], k_rope [B,T,1,dr]) — exactly what is cached."""
     c_kv = rms_norm(dense(p["w_dkv"], x), p["kv_norm"], cfg.norm_eps)
     k_r = dense(p["w_kr"], x)[:, :, None, :]  # single shared rope head
-    k_r = apply_rope(k_r, positions, cfg.rope_theta)
+    k_r = apply_rope(k_r, positions, cfg.rope_theta, cfg.rope_scaling)
     return c_kv, k_r
 
 
@@ -60,14 +65,15 @@ def mla_forward(cfg: ModelConfig, p: Params, x, positions, *,
     h, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     q_n, q_r = _split_q(cfg, dense(p["wq"], x))
-    q_r = apply_rope(q_r, positions, cfg.rope_theta)
+    q_r = apply_rope(q_r, positions, cfg.rope_theta, cfg.rope_scaling)
     c_kv, k_r = _latents(cfg, p, x, positions)
     k_n = dense(p["w_uk"], c_kv).reshape(b, t, h, dn)
     v = dense(p["w_uv"], c_kv).reshape(b, t, h, dv)
 
-    scale = 1.0 / np.sqrt(dn + dr)
+    scale = yarn_softmax_scale(dn + dr, cfg.rope_scaling)
     kv_pos = jnp.arange(t)
 
+    @jax.named_scope("mla.attend")
     def chunk(qn_i, qr_i, q_pos):
         from repro.models.layers import _SCORES_FP32
         if _SCORES_FP32:        # ablation baseline
@@ -133,7 +139,7 @@ def mla_decode(cfg: ModelConfig, p: Params, x, c_kv_cache, kr_cache, pos):
     r = cfg.kv_lora_rank
     q_n, q_r = _split_q(cfg, dense(p["wq"], x))          # [B,1,h,dn/dr]
     posv = jnp.full((1,), pos)
-    q_r = apply_rope(q_r, posv, cfg.rope_theta)
+    q_r = apply_rope(q_r, posv, cfg.rope_theta, cfg.rope_scaling)
     c_kv, k_r = _latents(cfg, p, x, posv)                # [B,1,r], [B,1,1,dr]
     c_kv_cache = jax.lax.dynamic_update_slice_in_dim(c_kv_cache, c_kv, pos, 1)
     kr_cache = jax.lax.dynamic_update_slice_in_dim(
@@ -144,7 +150,7 @@ def mla_decode(cfg: ModelConfig, p: Params, x, c_kv_cache, kr_cache, pos):
     # cache dtype (bf16 MXU) with fp32 accumulation (SPerf iteration A2)
     q_lat = jnp.einsum("bthd,rhd->bthr", q_n, w_uk,
                        preferred_element_type=jnp.float32)
-    scale = 1.0 / np.sqrt(dn + dr)
+    scale = yarn_softmax_scale(dn + dr, cfg.rope_scaling)
     lg = (jnp.einsum("bthr,bsr->bhts", q_lat.astype(c_kv_cache.dtype),
                      c_kv_cache, preferred_element_type=jnp.float32)
           + jnp.einsum("bthd,bsd->bhts", q_r, kr_cache,

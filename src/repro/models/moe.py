@@ -1,14 +1,29 @@
-"""Mixture-of-Experts layer: top-k router + capacity-based dispatch.
+"""Mixture-of-Experts layer: a top-k router and two ways to run the experts.
 
-TPU-native formulation, GShard/Switch-style: the token stream is split
-into G dispatch GROUPS (G = the ambient mesh's `data` size, 1 on a single
-device), each group gets its own capacity and a group-LOCAL cumsum for
-slot assignment, so dispatch never needs cross-shard prefix sums and the
-expert-major buffer [G, E, C_g, D] shards cleanly as
-P("data", "model", None, None) — experts over `model` (EP), groups over
-`data` (DP). Expert compute is one batched einsum over stacked expert
-weights (MXU friendly). Tokens beyond an expert's per-group capacity are
-dropped (classic GShard semantics); capacity_factor controls the rate.
+Serving runs **dropless** (``moe_dropless``): every (token, expert)
+assignment to an expert this device holds is computed, so a row's answer
+never depends on which rows share its dispatch. The assignments are sorted
+by expert and each projection is one grouped matrix product over the held
+experts' stacked weights (``kernels/expert_gmm``, the gate and up
+projections in one kernel), whose work follows the routed rows: there is
+no capacity and no ``[E, m, D]`` buffer. The layer may hold only a
+contiguous share of the experts (``ModelConfig.experts_held`` from
+``first_expert``), as one device of an expert-parallel
+deployment does: the router still scores every expert, assignments to
+absent experts add nothing here (their devices add them), and the shared
+experts are added once. The held experts' row counts, the product's group
+sizes, come back with the output for the device trace's counter.
+
+Training runs the **capacity** path (``moe_forward``), TPU-native and
+GShard/Switch-style: the token stream is split into G dispatch GROUPS (G
+= the ambient mesh's `data` size, 1 on a single device), each group gets
+its own capacity and a group-LOCAL cumsum for slot assignment, so
+dispatch never needs cross-shard prefix sums and the expert-major buffer
+[G, E, C_g, D] shards cleanly as P("data", "model", None, None) — experts
+over `model` (EP), groups over `data` (DP). Expert compute is one batched
+einsum over stacked expert weights (MXU friendly). Tokens beyond an
+expert's per-group capacity are dropped (classic GShard semantics);
+capacity_factor controls the rate.
 
 §Perf history: the original single-group global-cumsum dispatch forced
 XLA SPMD to REPLICATE the expert einsum on every chip (the scatter with
@@ -16,8 +31,11 @@ global indices could not be partitioned) — 256x redundant expert compute
 on the production mesh. The grouped formulation is iteration C3 in
 EXPERIMENTS.md.
 
-A load-balance auxiliary loss (Switch-style, computed over ALL tokens) is
-returned alongside.
+Both paths route alike: f32 router logits, softmax over all experts,
+greedy top-k; the top-k weights are renormalised only where the model
+does so (``norm_topk_prob``), then scaled by ``routed_scaling_factor``. A
+load-balance auxiliary loss (Switch-style, computed over ALL tokens) is
+returned alongside on the training path.
 """
 
 from __future__ import annotations
@@ -26,9 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.expert_gmm.ops import expert_gmm
 from repro.models.layers import Params, dense_params, swiglu, swiglu_params
-
-
 from repro.models.shard_hints import constrain as _constrain
 
 
@@ -43,11 +60,14 @@ def _dispatch_groups(n: int) -> int:
 
 
 def moe_params(key, cfg: ModelConfig, dtype) -> Params:
-    e, d, f = cfg.num_experts, cfg.d_model, cfg.moe_d_ff
+    """The router over all ``num_experts``; stacked weights of the held
+    experts only."""
+    _, e = cfg.held_experts
+    d, f = cfg.d_model, cfg.moe_d_ff
     ks = jax.random.split(key, 5)
     scale = 1.0 / jnp.sqrt(d)
     p = {
-        "router": dense_params(ks[0], d, e, jnp.float32),
+        "router": dense_params(ks[0], d, cfg.num_experts, jnp.float32),
         "w_gate": jax.random.normal(ks[1], (e, d, f)).astype(dtype) * scale,
         "w_up": jax.random.normal(ks[2], (e, d, f)).astype(dtype) * scale,
         "w_down": jax.random.normal(ks[3], (e, f, d)).astype(dtype)
@@ -88,36 +108,79 @@ def _group_combine(ye, flat_idx, weight, m: int, k: int):
     return jnp.sum((gathered * w[:, None]).reshape(m, k, d), axis=1)
 
 
-def moe_forward(cfg: ModelConfig, p: Params, x: jnp.ndarray,
-                capacity_factor: float | None = None,
-                dropless: bool = False):
-    """x: [B, T, D] -> (y [B, T, D], aux_loss scalar).
+def route(cfg: ModelConfig, router: Params, xf: jnp.ndarray):
+    """xf [N, D] -> (probs [N, E] over every expert, top-k weights [N, k],
+    top-k experts [N, k])."""
+    logits = (xf.astype(jnp.float32)
+              @ router["w"].astype(jnp.float32))             # [N, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        top_p = top_p * cfg.routed_scaling_factor
+    return probs, top_p, top_e
 
-    ``dropless=True`` sizes the per-expert capacity so no token can be
-    dropped (each token occupies at most one slot per expert, so cap = m
-    suffices). Serving paths use it: capacity dropping is a training
-    throughput tradeoff, and it breaks prefill/decode equivalence — the
-    same token drops in a crowded prefill but not in a 1-token decode."""
+
+def moe_dropless(cfg: ModelConfig, p: Params, x: jnp.ndarray):
+    """Serving path. x [B, T, D] -> (y [B, T, D], held_rows [held] int32):
+    the held experts' part of the routed sum plus the shared experts, and
+    how many assignments each held expert computed."""
+    b, t, d = x.shape
+    n, k = b * t, cfg.num_experts_per_tok
+    first, held = cfg.held_experts
+    xf = x.reshape(n, d)
+    with jax.named_scope("moe.route"):
+        _, top_p, top_e = route(cfg, p["router"], xf)
+    with jax.named_scope("moe.dispatch"):
+        local = top_e.reshape(n * k) - first             # token-major
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)             # absent: last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        xs = xf[order // k]                              # [N*k, D]
+    with jax.named_scope("moe.expert_gmm"):
+        h = expert_gmm(xs, p["w_gate"], sizes, p["w_up"])  # silu(g) * u
+        ys = expert_gmm(h, p["w_down"], sizes)           # [N*k, D]
+    with jax.named_scope("moe.combine"):
+        # each assignment's row in the sorted order, token-major [N, k]
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=order.dtype)).reshape(n, k)
+        mine = mine.reshape(n, k)
+        # one gather per slot, summed in one fusion; rows past the held
+        # experts' total are undefined: select, never multiply, them away
+        y = jnp.zeros((n, d), jnp.float32)
+        for j in range(k):
+            y = y + jnp.where(mine[:, j:j + 1],
+                              ys[back[:, j]].astype(jnp.float32)
+                              * top_p[:, j:j + 1], 0.0)
+        y = y.astype(x.dtype)
+        if cfg.num_shared_experts:
+            y = y + swiglu(p["shared"], xf)
+    return y.reshape(b, t, d), sizes
+
+
+def moe_forward(cfg: ModelConfig, p: Params, x: jnp.ndarray,
+                capacity_factor: float | None = None):
+    """Training path. x: [B, T, D] -> (y [B, T, D], aux_loss scalar).
+    Tokens past an expert's per-group capacity are dropped, so a token's
+    output depends on its batch: serving uses ``moe_dropless``."""
     b, t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
+    if cfg.held_experts != (0, e):
+        raise ValueError("the capacity path needs every expert; a share "
+                         "of them serves through moe_dropless")
     n = b * t
     g = _dispatch_groups(n)
     m = n // g                                              # tokens/group
-    if dropless:
-        cap = m
-    else:
-        cf = (cfg.capacity_factor if capacity_factor is None
-              else capacity_factor)
-        cap = max(int(m * k * cf / e), 1)
+    cf = (cfg.capacity_factor if capacity_factor is None
+          else capacity_factor)
+    cap = max(int(m * k * cf / e), 1)
     # round capacity to a lane-friendly multiple of 8
     cap = (cap + 7) // 8 * 8
 
     xf = x.reshape(n, d)
-    router_logits = (xf.astype(jnp.float32)
-                     @ p["router"]["w"].astype(jnp.float32))      # [N, E]
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)                        # [N, k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)        # renormalise
+    probs, top_p, top_e = route(cfg, p["router"], xf)      # [N, k]
 
     # ---- load-balance aux loss (Switch): E * sum_e f_e * P_e ----
     me = jnp.mean(probs, axis=0)                                   # [E]

@@ -131,7 +131,10 @@ def _embed_in(cfg: ModelConfig, params: Params, batch: Batch) -> jnp.ndarray:
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
-def _attn_body(cfg: ModelConfig, lp: Params, x, positions, *, causal, moe):
+def _attn_body(cfg: ModelConfig, lp: Params, x, positions, *, causal, moe,
+               dropless: bool = False):
+    """One block. Returns (x, aux loss, the held experts' row counts: only
+    from a dropless MoE block, else None)."""
     h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     if cfg.use_mla:
         a, _ = mla_mod.mla_forward(cfg, lp["attn"], h, positions,
@@ -140,11 +143,15 @@ def _attn_body(cfg: ModelConfig, lp: Params, x, positions, *, causal, moe):
         a = attn_forward(cfg, lp["attn"], h, positions, causal=causal)
     x = x + a
     h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-    if moe:
+    rows = None
+    if moe and dropless:
+        y, rows = moe_mod.moe_dropless(cfg, lp["moe"], h)
+        aux = jnp.float32(0.0)
+    elif moe:
         y, aux = moe_mod.moe_forward(cfg, lp["moe"], h)
     else:
         y, aux = swiglu(lp["mlp"], h), jnp.float32(0.0)
-    return x + y, aux
+    return x + y, aux, rows
 
 
 def _rwkv_body(cfg: ModelConfig, lp: Params, x, st):
@@ -157,24 +164,28 @@ def _rwkv_body(cfg: ModelConfig, lp: Params, x, st):
     return x + out, {"wkv": wkv, "tm_prev": tm_last, "cm_prev": cm_last}
 
 
-def _run_attn_stack(cfg, params, x, positions, *, causal, remat: bool):
+def _run_attn_stack(cfg, params, x, positions, *, causal, remat: bool,
+                    dropless: bool = False):
+    """Returns (x, aux loss, held-expert row counts [MoE layers, held] of a
+    dropless MoE stack, else None)."""
     aux_total = jnp.float32(0.0)
 
     def mk_body(moe):
         def body(carry, lp):
             x, aux = carry
-            x, a = _attn_body(cfg, lp, x, positions, causal=causal, moe=moe)
-            return (x, aux + a), None
+            x, a, rows = _attn_body(cfg, lp, x, positions, causal=causal,
+                                    moe=moe, dropless=dropless)
+            return (x, aux + a), rows
         return jax.checkpoint(body) if remat else body
 
     if "dense_blocks" in params:
         (x, aux_total), _ = jax.lax.scan(mk_body(False), (x, aux_total),
                                          params["dense_blocks"],
                                          unroll=scan_unroll())
-    (x, aux_total), _ = jax.lax.scan(mk_body(cfg.is_moe), (x, aux_total),
-                                     params["blocks"],
-                                     unroll=scan_unroll())
-    return x, aux_total
+    (x, aux_total), rows = jax.lax.scan(mk_body(cfg.is_moe), (x, aux_total),
+                                        params["blocks"],
+                                        unroll=scan_unroll())
+    return x, aux_total, rows
 
 
 def _run_rwkv_stack(cfg, params, x, state, *, remat: bool):
@@ -229,17 +240,23 @@ def _run_zamba_stack(cfg, params, x, positions, mamba_state, attn_fn,
 
 
 def forward(cfg: ModelConfig, params: Params, batch: Batch, *,
-            remat: bool = False):
-    """Full-sequence hidden states [B,T,D] (+ aux dict)."""
+            remat: bool = False, dropless: bool = False):
+    """Full-sequence hidden states [B,T,D] (+ aux dict). ``dropless`` runs
+    MoE layers as served (``moe.moe_dropless``); the aux dict then holds
+    ``moe_held_rows`` [MoE layers, held experts], the rows each held
+    expert computed."""
     x = _embed_in(cfg, params, batch)
     b, t, _ = x.shape
     positions = jnp.arange(t)
     causal = not cfg.is_encoder
 
     if cfg.block_type == "attn":
-        x, aux = _run_attn_stack(cfg, params, x, positions, causal=causal,
-                                 remat=remat)
+        x, aux, rows = _run_attn_stack(cfg, params, x, positions,
+                                       causal=causal, remat=remat,
+                                       dropless=dropless)
         extras = {"moe_aux": aux}
+        if rows is not None:
+            extras["moe_held_rows"] = rows
     elif cfg.block_type == "rwkv6":
         state = rwkv.rwkv6_state(cfg, b)
         x, _ = _run_rwkv_stack(cfg, params, x, state, remat=remat)
@@ -393,7 +410,7 @@ def prefill(cfg: ModelConfig, params: Params, batch: Batch):
             x = x + a
             h = rms_norm(x, lp["norm2"], cfg.norm_eps)
             if "moe" in lp:
-                y, _ = moe_mod.moe_forward(cfg, lp["moe"], h, dropless=True)
+                y, _ = moe_mod.moe_dropless(cfg, lp["moe"], h)
             else:
                 y = swiglu(lp["mlp"], h)
             return x + y, kv
@@ -452,7 +469,7 @@ def decode_step(cfg: ModelConfig, params: Params, token, cache, pos):
             x = x + a
             h = rms_norm(x, lp["norm2"], cfg.norm_eps)
             if "moe" in lp:
-                y, _ = moe_mod.moe_forward(cfg, lp["moe"], h, dropless=True)
+                y, _ = moe_mod.moe_dropless(cfg, lp["moe"], h)
             else:
                 y = swiglu(lp["mlp"], h)
             return x + y, new_kv

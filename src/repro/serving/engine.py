@@ -324,6 +324,11 @@ def make_gated_local_step(local_apply: Callable, supervisor="max_softmax",
     ``emit`` opts into in-kernel early emit (DESIGN.md §11): the step
     gains a trailing ``seq`` arg and the gate surfaces its triple to
     ``emit(seq, conf, pred, idx)`` on the host the moment it lands.
+
+    A ``counted`` ``FusedLocalHead`` adds its trunk's counters to the
+    outputs (``counters``, summed over the mesh's shards) and to the
+    ``emit`` call, so they reach the host in the transfer the triple
+    makes; any other local tier compiles the step it compiled before.
     """
     rows = None if mesh is None else batch_axes(mesh)
     # data-parallel: select over every shard's rows, in global order
@@ -331,34 +336,43 @@ def make_gated_local_step(local_apply: Callable, supervisor="max_softmax",
               (lambda conf: jax.lax.all_gather(conf, rows, tiled=True)))
 
     fused = isinstance(local_apply, FusedLocalHead)
+    counted = fused and local_apply.counted
 
     def gate(local_batch, t_local, n_valid):
         # stable names for the device trace: the trunk's ops and the
         # gate's kernels carry these scopes in their op metadata
         with jax.named_scope("trunk"):
-            x = (local_apply.trunk(local_batch) if fused
-                 else local_apply(local_batch))
+            x, counters = (local_apply.split(local_batch) if fused
+                           else (local_apply(local_batch), None))
         with jax.named_scope("gate"):
             if fused:
-                return fused_head_gate(x, local_apply.w, local_apply.bias,
-                                       t_local, n_valid,
-                                       supervisor=supervisor, gather=gather)
-            return confidence_gate(x, t_local, n_valid,
-                                   supervisor=supervisor, gather=gather)
+                out = fused_head_gate(x, local_apply.w, local_apply.bias,
+                                      t_local, n_valid,
+                                      supervisor=supervisor, gather=gather)
+            else:
+                out = confidence_gate(x, t_local, n_valid,
+                                      supervisor=supervisor, gather=gather)
+        if counted:
+            out["counters"] = (counters if rows is None
+                               else jax.lax.psum(counters, rows))
+        return out
 
     if mesh is not None:
+        specs = {"conf": P(rows), "pred": P(rows), "idx": P()}
+        if counted:
+            specs["counters"] = P()
         gate = jax.shard_map(
             gate, mesh=mesh, in_specs=(P(rows), P(), P()),
-            out_specs={"conf": P(rows), "pred": P(rows), "idx": P()},
-            check_vma=False)
+            out_specs=specs, check_vma=False)
 
     if emit is None:
         return gate
 
     def step(local_batch, t_local, n_valid, seq):
         out = gate(local_batch, t_local, n_valid)
+        extra = (out["counters"],) if counted else ()
         io_callback(emit, None, jnp.asarray(seq, jnp.int32), out["conf"],
-                    out["pred"], out["idx"], ordered=False)
+                    out["pred"], out["idx"], *extra, ordered=False)
         return out
 
     return step
@@ -706,11 +720,12 @@ class CascadeEngine:
         self.t_local = t
 
     # -- in-kernel early emit (DESIGN.md §11) ---------------------------
-    def _on_gate(self, seq, conf, pred, idx) -> None:
+    def _on_gate(self, seq, conf, pred, idx, counters=None) -> None:
         """io_callback target: the gate's (conf, pred, idx) triple for
-        window ``seq`` just landed on the host. Runs whenever the device
-        forces the computation — possibly on a transport thread — so it
-        only stores and signals; consumers poll ``gate_result``."""
+        window ``seq`` just landed on the host, with a counted trunk's
+        counters. Runs whenever the device forces the computation —
+        possibly on a transport thread — so it only stores, records and
+        signals; consumers poll ``gate_result``."""
         if int(seq) == 0:               # the warm-up dispatch, no window
             return
         obs = self.observability
@@ -721,7 +736,25 @@ class CascadeEngine:
                                                 np.asarray(pred).copy(),
                                                 np.asarray(idx).copy())
                 self._gate_emits += 1
+            if counters is not None:
+                self._record_counters(int(seq), counters)
             self._ready.set()
+
+    def _record_counters(self, seq: int, counters: dict) -> None:
+        """A counted trunk's device counters for window ``seq``, as one
+        record each on the observability trace (``{"counter": name,
+        "window", "t": engine clock, "value": nested list, "stages":
+        []}``, beside the per-request spans). Early emit records them
+        from its callback, a fetch from the gate half, so each window's
+        land once."""
+        obs = self.observability
+        if obs is None or obs.trace is None:
+            return
+        t = self._clock()
+        for name, value in counters.items():
+            obs.trace.emit({"counter": name, "window": seq, "t": t,
+                            "value": np.asarray(value).tolist(),
+                            "stages": []})
 
     def gate_result(self, seq: int):
         """The early-emitted gate triple for window ``seq`` (``(conf,
@@ -1053,6 +1086,8 @@ class CascadeEngine:
                 fl.conf = np.asarray(gate["conf"])
                 fl.local_pred = np.asarray(gate["pred"])
                 cand = gate["idx"]
+                if "counters" in gate and not self.early_emit:
+                    self._record_counters(fl.seq, gate["counters"])
             fl.gate_dev = None
             fl.pred = fl.local_pred.copy()
             cand = np.asarray(cand)

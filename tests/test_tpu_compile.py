@@ -198,3 +198,94 @@ def test_data_parallel_gated_step_with_early_emit_compiles(topo, on_tpu):
         jax.ShapeDtypeStruct((), jnp.float32, sharding=rep), scalar, scalar))
     assert "tpu_custom_call" in hlo
     assert "callback" in hlo.lower()
+
+
+def test_expert_gmm_compiles_at_the_served_widths(one_chip):
+    """DeepSeek-V2-Lite's expert product: rows sorted by 16 held experts,
+    d_model 2048 against the expert width 1408 (11 x 128) both ways, the
+    gate and up projections in one kernel."""
+    from repro.kernels.expert_gmm.kernel import expert_gmm_pallas
+    m, d, f, e = 32 * 512 * 6, 2048, 1408, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for k, n, glu in ((d, f, True), (f, d, False)):
+        up = sds((e, k, n), jnp.bfloat16) if glu else None
+        hlo = _hlo(jax.jit(lambda x, w, g, u: expert_gmm_pallas(
+            x, w, g, u)).lower(sds((m, k), jnp.bfloat16),
+                               sds((e, k, n), jnp.bfloat16),
+                               sds((e,), jnp.int32), up))
+        assert re.search(r"^\s*(ROOT )?%?expert_gmm\S* = .*tpu_custom_call",
+                         hlo, re.M)
+
+
+def test_moe_mla_gated_step_compiles_at_full_width(one_chip, on_tpu,
+                                                   monkeypatch):
+    """The served DeepSeek-V2-Lite trunk at every published width (two of
+    its 27 layers: the dense one and one expert layer), with the expert
+    product and both gate kernels on their Pallas paths, and the held
+    rows counted beside the gate's outputs."""
+    from chipbench import spec
+    from chipbench.tiers import moe_lm_classifier as M
+    from chipbench.weights import moe_decoder as W
+    from repro.kernels.expert_gmm import ops as gmm_ops
+    from repro.kernels.fused_head_gate.ops import FusedLocalHead
+    from repro.models import transformer as T
+    from repro.serving.engine import make_gated_local_step
+    monkeypatch.setattr(gmm_ops, "_on_tpu", lambda: True)
+    cfg = spec.load_json(spec.ROOT / "chipbench" / "configs" /
+                         "dsv2lite-ep4-lm-cls.json")
+    cfg["model"]["num_hidden_layers"] = 2
+    mcfg = M.model_config(cfg)
+    shapes = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        W.program_shapes(cfg["model"]))
+
+    def fn(params, tokens, t, n, seq):
+        def trunk(x):
+            h, extras = T.forward(mcfg, params, {"tokens": x}, dropless=True)
+            return h[:, -1], {M.COUNTER: extras["moe_held_rows"]}
+        head = FusedLocalHead(trunk, params["head"]["w"], None, counted=True)
+        return make_gated_local_step(head, emit=lambda *a: None)(
+            tokens, t, n, seq)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn).lower(
+        shapes, jax.ShapeDtypeStruct((SERVE_BATCH, 512), jnp.int32,
+                                     sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
+        scalar, scalar).compile()
+    kernels = re.findall(
+        r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text(), re.M)
+    # gate and up in one product, down, the head gate's scoring and
+    # selection
+    assert sum(k.startswith("expert_gmm") for k in kernels) == 2, kernels
+    assert sum(k.startswith("head_gate_scores_pallas") for k in kernels) == 1
+    out = jax.eval_shape(fn, shapes, jax.ShapeDtypeStruct(
+        (SERVE_BATCH, 512), jnp.int32), jax.ShapeDtypeStruct((), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    assert out["counters"][M.COUNTER].shape == (1, 16)
+
+
+def test_data_parallel_counted_step_compiles_on_four_chips(topo, on_tpu):
+    """A counted trunk under the data-parallel mesh: its counters are
+    summed over the row shards and come back replicated."""
+    from repro.kernels.fused_head_gate.ops import FusedLocalHead
+    from repro.serving.engine import make_gated_local_step
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+    w = jnp.zeros((24, 128), jnp.float32)
+    head = FusedLocalHead(
+        lambda x: (x.astype(jnp.float32),
+                   {"rows": jnp.sum(x > 0, 0).astype(jnp.int32)}),
+        w, None, counted=True)
+    step = jax.jit(make_gated_local_step(head, mesh=mesh))
+    rows = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    compiled = step.lower(
+        jax.ShapeDtypeStruct((SERVE_BATCH, 24), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    assert compiled.output_shardings["counters"]["rows"].spec == P()
